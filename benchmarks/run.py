@@ -47,6 +47,8 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names to run")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         # set BEFORE bench modules import common-driven size constants
         os.environ["REPRO_BENCH_SMOKE"] = "1"

@@ -26,14 +26,9 @@ FSDP_THRESHOLD = 500_000_000   # params; above this, shard "embed" on data
 
 
 def shard_map_compat(f, *, mesh, in_specs, out_specs, check=False):
-    """``jax.shard_map`` across jax versions (0.4.x keeps it in
-    jax.experimental with ``check_rep`` instead of ``check_vma``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check)
+    """``jax.shard_map`` with its replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -2861,12 +2861,15 @@ def _fused_scan_steps(carry, xs, rows_t, rows_v, t_first32, t_last32,
         # (vg * mg == 0) and the clip keeps f_lo finite even at -inf
         ov = f_g[None, :, :] - f_lo                          # (D, P, B)
         # coverage-pattern one-hot: the windowed dict-of-patterns as a
-        # dense (D, 2^K, P, K) accumulate
-        pows = 2.0 ** jnp.arange(k, dtype=jnp.float64)
-        pat = (mg * pows[None, :, None]).sum(axis=1)         # (D, B)
+        # dense (D, 2^K, P, K) accumulate.  The pattern is built from
+        # integer bits: a TPU emulates float64, and there 2.0 ** j is
+        # inexact, so float patterns never equal the one-hot's codes
+        bits = (mg > 0).astype(jnp.int32) \
+            << jnp.arange(k, dtype=jnp.int32)[None, :, None]
+        pat = bits.sum(axis=1, dtype=jnp.int32)              # (D, B)
         qn = integrals.shape[1]
         onehot = (pat[:, None, :]
-                  == jnp.arange(qn, dtype=jnp.float64)[None, :, None])
+                  == jnp.arange(qn, dtype=jnp.int32)[None, :, None])
         integrals = integrals + jnp.einsum(
             'dqj,dpj,dkj->dqpk', onehot.astype(jnp.float64), ov,
             vg * mg)
@@ -2921,7 +2924,6 @@ def attribute_totals_fused_scan(rows: StreamRows, group_sizes, phases,
     slots-per-step width (compiled shape).  Returns a ``ScanResult``.
     """
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
     group_sizes = list(group_sizes)
     n = int(sum(group_sizes))
     assert n == rows.n_streams, (n, rows.n_streams)
@@ -3044,7 +3046,7 @@ def attribute_totals_fused_scan(rows: StreamRows, group_sizes, phases,
         carry0 = (np.zeros((n,)), np.zeros((n,)),
                   np.full((d,), -np.inf), np.zeros((d,), bool),
                   np.zeros((d, qn, p, k_max)))
-        with enable_x64():
+        with jax.enable_x64(True):
             carry = _fused_scan_steps(
                 jax.tree.map(jnp.asarray, carry0),
                 jax.tree.map(jnp.asarray, xs),

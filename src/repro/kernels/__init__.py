@@ -26,3 +26,17 @@ def auto_block_rows(n_rows: int, block_rows, interpret: bool,
     if block_rows is None:
         block_rows = n_rows if interpret else compiled_rows
     return min(block_rows, n_rows)
+
+
+def pad_rows(block_rows: int, *arrays):
+    """Zero-pad each array's row axis to a multiple of ``block_rows``.
+
+    Compiled kernels tile rows in fixed blocks; callers slice the first
+    ``n`` output rows back, so the zero rows never surface.
+    """
+    pad = (-arrays[0].shape[0]) % block_rows
+    if not pad:
+        return arrays
+    import jax.numpy as jnp
+    return tuple(jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                 for a in arrays)

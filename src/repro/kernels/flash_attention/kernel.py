@@ -35,10 +35,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, seq_len,
 
     def body(j, carry):
         m_c, l_c, acc_c = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = q @ k.T                                       # (bq, bk)
         if logit_cap:
             s = logit_cap * jnp.tanh(s / logit_cap)

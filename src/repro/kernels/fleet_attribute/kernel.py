@@ -51,13 +51,14 @@ def fleet_attribute_kernel(times, energy, wrap_row, phases, *,
 
     ``block_rows=None`` auto-sizes via ``kernels.auto_block_rows``.
     """
-    from repro.kernels import auto_block_rows
+    from repro.kernels import auto_block_rows, pad_rows
     n, s = times.shape
     p = phases.shape[0]
     block_rows = auto_block_rows(n, block_rows, interpret)
     block_phases = min(block_phases, p)
-    assert n % block_rows == 0 and p % block_phases == 0
-    grid = (n // block_rows, p // block_phases)
+    assert p % block_phases == 0
+    rows = pad_rows(block_rows, times, energy, wrap_row)
+    grid = (rows[0].shape[0] // block_rows, p // block_phases)
     return pl.pallas_call(
         _fa_kernel,
         grid=grid,
@@ -69,6 +70,6 @@ def fleet_attribute_kernel(times, energy, wrap_row, phases, *,
         ],
         out_specs=pl.BlockSpec((block_rows, block_phases),
                                lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, p), energy.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows[0].shape[0], p), energy.dtype),
         interpret=interpret,
-    )(times, energy, wrap_row, phases)
+    )(*rows, phases)[:n]

@@ -26,9 +26,9 @@ def grid_resample(times, values, n_row, first_row, grid, delays, *,
 
     ``use_kernel=None`` auto-dispatches: the Pallas kernel when
     compiled, the bit-identical sort-based jnp lower bound under
-    interpret (CPU) — per-iteration gathers dominate the halving loop
-    there and XLA's sort lowering is ~2x faster.  ``True`` forces the
-    kernel (parity tests), ``False`` the loop-based jnp oracle.
+    interpret (CPU), where emulating the kernel's column sweep costs
+    far more than XLA's sort lowering.  ``True`` forces the kernel
+    (parity tests), ``False`` the loop-based jnp oracle.
     """
     n_row = jnp.reshape(n_row, (-1, 1)).astype(jnp.int32)
     first_row = jnp.reshape(first_row, (-1, 1)).astype(jnp.int32)

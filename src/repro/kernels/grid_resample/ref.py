@@ -28,7 +28,7 @@ def searchsorted_rows(t, target, lo, hi, *, xp=jnp):
     t: (R, S) row-sorted times; target: (R, G); lo/hi: (R, 1) int32 search
     bounds (``lo`` skips leading undefined slots, ``hi`` masks padding).
     A fixed ``ceil(log2(S)) + 1`` halving steps — branch-free, identical
-    math in the Pallas kernel, the jnp oracle and (xp=numpy) host mirror.
+    math in the jnp oracle and (xp=numpy) the host mirror.
     """
     s = t.shape[1]
     lo = xp.broadcast_to(lo.astype(xp.int32), target.shape)
@@ -50,8 +50,8 @@ def searchsorted_rows_sorted(t, target, lo, hi):
     where per-iteration gathers dominate the loop.  Masking: slots
     before ``lo`` clamp to -inf and slots at/after ``hi`` to +inf, which
     keeps each row sorted and pushes them out of every query's range.
-    Used by the non-kernel (jnp) path; the Pallas kernel keeps the
-    branch-free loop (Mosaic has no sort).
+    Used by the non-kernel (jnp) path; the Pallas kernel sweeps the
+    columns instead (Mosaic lowers neither sort nor this gather).
     """
     s = t.shape[1]
     j = jnp.arange(s)[None, :]
@@ -64,7 +64,7 @@ def searchsorted_rows_sorted(t, target, lo, hi):
 def grid_resample_ref(times, values, n_row, first_row, grid, delays,
                       *, mode: str = "hold", xp=jnp,
                       sorted_search: bool = False):
-    """Canonical regrid semantics shared by kernel/oracle/host mirror.
+    """Canonical regrid semantics: the kernel's oracle and host mirror.
 
     times/values: (R, S); n_row/first_row/delays: (R, 1); grid: (G, 1).
     Returns (out, mask): out[r, g] is the stream's value at
@@ -73,7 +73,7 @@ def grid_resample_ref(times, values, n_row, first_row, grid, delays,
     mask marks grid points inside the row's defined span
     [t[first], t[n-1]].  ``sorted_search`` (jnp only) swaps the halving
     loop for the bit-identical sort-based lower bound — the fast CPU
-    path; the Pallas kernel always uses the loop.
+    path.
     """
     r, s = times.shape
     ge = grid[:, 0][None, :] + delays            # (R, G) shifted queries
@@ -97,7 +97,13 @@ def grid_resample_ref(times, values, n_row, first_row, grid, delays,
         t_hi = xp.take_along_axis(times, xp.clip(j_hi, 0, s - 1), axis=1)
         v_lo = xp.take_along_axis(values, xp.clip(j_lo, 0, s - 1), axis=1)
         v_hi = xp.take_along_axis(values, xp.clip(j_hi, 0, s - 1), axis=1)
-        frac = xp.clip((ge - t_lo) / xp.maximum(t_hi - t_lo, 1e-12),
-                       0.0, 1.0)
-        out = v_lo + frac * (v_hi - v_lo)
+        out = linear_interp(ge, t_lo, t_hi, v_lo, v_hi, xp=xp)
     return xp.where(mask, out, 0.0), mask
+
+
+def linear_interp(q, t_lo, t_hi, v_lo, v_hi, *, xp=jnp):
+    """Value at ``q`` on the segment (t_lo, v_lo)-(t_hi, v_hi), clamped
+    to its ends; the one expression the oracle and the kernel's wrapper
+    both evaluate, so their results agree bit for bit."""
+    frac = xp.clip((q - t_lo) / xp.maximum(t_hi - t_lo, 1e-12), 0.0, 1.0)
+    return v_lo + frac * (v_hi - v_lo)

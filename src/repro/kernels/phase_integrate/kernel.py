@@ -37,13 +37,14 @@ def phase_integrate_kernel(times, watts, phases, *, block_rows=None,
 
     ``block_rows=None`` auto-sizes via ``kernels.auto_block_rows``.
     """
-    from repro.kernels import auto_block_rows
+    from repro.kernels import auto_block_rows, pad_rows
     n, s = times.shape
     p = phases.shape[0]
     block_rows = auto_block_rows(n, block_rows, interpret)
     block_phases = min(block_phases, p)
-    assert n % block_rows == 0 and p % block_phases == 0
-    grid = (n // block_rows, p // block_phases)
+    assert p % block_phases == 0
+    rows = pad_rows(block_rows, times, watts)
+    grid = (rows[0].shape[0] // block_rows, p // block_phases)
     return pl.pallas_call(
         _pi_kernel,
         grid=grid,
@@ -54,6 +55,6 @@ def phase_integrate_kernel(times, watts, phases, *, block_rows=None,
         ],
         out_specs=pl.BlockSpec((block_rows, block_phases),
                                lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((n, p), watts.dtype),
+        out_shape=jax.ShapeDtypeStruct((rows[0].shape[0], p), watts.dtype),
         interpret=interpret,
-    )(times, watts, phases)
+    )(*rows, phases)[:n]

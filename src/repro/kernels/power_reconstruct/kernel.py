@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels import auto_block_rows
+from repro.kernels import auto_block_rows, pad_rows
 from repro.kernels.power_reconstruct.ref import wrapped_diff
 
 
@@ -70,20 +70,20 @@ def _pr_fleet_kernel(e_ref, t_ref, w_ref, n_ref, p_ref, v_ref, r_ref):
     # dedup + monotonic in one comparison: cached re-reads republish an
     # unchanged (t, E) pair (==) and jitter can reorder timestamps (<) —
     # keep iff t strictly advanced; slot 0 is kept when the row is live
-    adv = jnp.pad(t[:, 1:] > t[:, :-1], ((0, 0), (1, 0)),
-                  constant_values=True)
+    t_prev = jnp.pad(t[:, :-1], ((0, 0), (1, 0)))
+    adv = (idx == 0) | (t > t_prev)
     keep = valid & adv
     de = wrapped_diff(e, w)
     dt = t[:, 1:] - t[:, :-1]
     p = jnp.pad(de / jnp.maximum(dt, 1e-12), ((0, 0), (1, 0)))
     valid_out = keep & (idx >= 1)
     p_ref[...] = jnp.where(valid_out, p, 0.0)
-    v_ref[...] = valid_out
+    # masks leave as int32: Mosaic cannot store a boolean vector
+    v_ref[...] = valid_out.astype(jnp.int32)
     # raw adjacent diffs only bridge duplicate runs when nothing is
     # reordered — flag rows that need the carry-forward fallback
-    r_ref[...] = jnp.any(valid[:, 1:] & valid[:, :-1]
-                         & (t[:, 1:] < t[:, :-1]),
-                         axis=1, keepdims=True)
+    back = valid[:, 1:] & valid[:, :-1] & (t[:, 1:] < t[:, :-1])
+    r_ref[...] = jnp.max(back.astype(jnp.int32), axis=1, keepdims=True)
 
 
 def power_reconstruct_fleet_kernel(energy, times, wrap_row, n_row, *,
@@ -100,9 +100,9 @@ def power_reconstruct_fleet_kernel(energy, times, wrap_row, n_row, *,
     """
     n, s = energy.shape
     block_rows = auto_block_rows(n, block_rows, interpret)
-    assert n % block_rows == 0
-    grid = (n // block_rows,)
-    return pl.pallas_call(
+    args = pad_rows(block_rows, energy, times, wrap_row, n_row)
+    grid = (args[0].shape[0] // block_rows,)
+    power, valid, reordered = pl.pallas_call(
         _pr_fleet_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, s), lambda i: (i, 0)),
@@ -112,11 +112,12 @@ def power_reconstruct_fleet_kernel(energy, times, wrap_row, n_row, *,
         out_specs=[pl.BlockSpec((block_rows, s), lambda i: (i, 0)),
                    pl.BlockSpec((block_rows, s), lambda i: (i, 0)),
                    pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n, s), energy.dtype),
-                   jax.ShapeDtypeStruct((n, s), jnp.bool_),
-                   jax.ShapeDtypeStruct((n, 1), jnp.bool_)],
+        out_shape=[jax.ShapeDtypeStruct(args[0].shape, energy.dtype),
+                   jax.ShapeDtypeStruct(args[0].shape, jnp.int32),
+                   jax.ShapeDtypeStruct((args[0].shape[0], 1), jnp.int32)],
         interpret=interpret,
-    )(energy, times, wrap_row, n_row)
+    )(*args)
+    return power[:n], valid[:n] != 0, reordered[:n] != 0
 
 
 def power_reconstruct_rows_kernel(energy, times, wrap_row, *,
@@ -130,8 +131,8 @@ def power_reconstruct_rows_kernel(energy, times, wrap_row, *,
     """
     n, s = energy.shape
     block_rows = auto_block_rows(n, block_rows, interpret)
-    assert n % block_rows == 0
-    grid = (n // block_rows,)
+    args = pad_rows(block_rows, energy, times, wrap_row)
+    grid = (args[0].shape[0] // block_rows,)
     return pl.pallas_call(
         _pr_rows_kernel,
         grid=grid,
@@ -139,6 +140,6 @@ def power_reconstruct_rows_kernel(energy, times, wrap_row, *,
                   pl.BlockSpec((block_rows, s), lambda i: (i, 0)),
                   pl.BlockSpec((block_rows, 1), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, s), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, s), energy.dtype),
+        out_shape=jax.ShapeDtypeStruct(args[0].shape, energy.dtype),
         interpret=interpret,
-    )(energy, times, wrap_row)
+    )(*args)[:n]

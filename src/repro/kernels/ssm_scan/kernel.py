@@ -23,18 +23,17 @@ def _ssm_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hout_ref,
     h = h0_ref[...].astype(jnp.float32)           # (bd, N)
 
     def body(t, h):
-        dt = pl.load(dt_ref, (pl.dslice(t, 1), slice(None)))[0]   # (bd,)
-        x = pl.load(x_ref, (pl.dslice(t, 1), slice(None)))[0]
-        bt = pl.load(b_ref, (pl.dslice(t, 1), slice(None)))[0]    # (N,)
-        ct = pl.load(c_ref, (pl.dslice(t, 1), slice(None)))[0]
+        dt = dt_ref[pl.ds(t, 1), :][0]                             # (bd,)
+        x = x_ref[pl.ds(t, 1), :][0]
+        bt = b_ref[pl.ds(t, 1), :][0]                              # (N,)
+        ct = c_ref[pl.ds(t, 1), :][0]
         dtf = dt.astype(jnp.float32)
         abar = jnp.exp(dtf[:, None] * a)                           # (bd, N)
         bx = (dtf * x.astype(jnp.float32))[:, None] \
             * bt.astype(jnp.float32)[None, :]
         h = abar * h + bx
         y = jnp.sum(h * ct.astype(jnp.float32)[None, :], axis=-1)  # (bd,)
-        pl.store(y_ref, (pl.dslice(t, 1), slice(None)),
-                 y[None].astype(y_ref.dtype))
+        y_ref[pl.ds(t, 1), :] = y[None].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, seq_len, body, h)

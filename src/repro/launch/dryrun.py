@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: ``.lower().compile()`` every (arch × shape × mesh)
 cell.
 
@@ -19,6 +16,7 @@ Usage::
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -29,6 +27,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, SHAPES, cell_is_runnable, get_arch, get_shape
 from repro.distributed.sharding import make_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_costs import analyze as hlo_analyze
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import input_specs
@@ -167,8 +166,6 @@ def lower_cell(arch_name, shape_name, *, multi_pod=False, compile_opts=None):
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax <= 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     acc = hlo_analyze(hlo)            # trip-count-aware (see hlo_costs.py)
     coll_by_type = acc["collectives"]
@@ -283,6 +280,10 @@ def run_cells(cells, out_dir, meshes=(False, True)):
 
 
 def main():
+    # placeholder host devices for the production meshes: must be set
+    # before the first device query initializes the CPU backend
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

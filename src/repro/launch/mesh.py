@@ -7,16 +7,10 @@ one CPU device, while ``dryrun.py`` forces 512 placeholder host devices.
 from __future__ import annotations
 
 import jax
-
-try:                       # jax >= 0.5: explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:        # 0.4.x meshes are implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(devices, axes):
-    if AxisType is None:
-        return jax.sharding.Mesh(devices, axes)
     return jax.sharding.Mesh(devices, axes,
                              axis_types=(AxisType.Auto,) * len(axes))
 

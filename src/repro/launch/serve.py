@@ -1,12 +1,16 @@
-"""Serving driver: batched requests against any arch (reduced on CPU),
-with phase-level power/energy attribution of the serving timeline.
+"""Serving entry point: batched requests against any arch at its published
+widths (bf16 parameters), with phase-level power/energy attribution of
+the serving timeline.  ``--reduced`` serves a tiny same-family config
+instead, for CPU runs.
 
   PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b \
       --requests 12 --max-new 16
+  PYTHONPATH=src python -m repro.launch.serve --reduced   # on a CPU
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import numpy as np
@@ -15,11 +19,28 @@ from repro.configs import get_arch, reduced as reduce_cfg
 from repro.core import NodeFabric, ToolSpec, attribute_energy, phase_power
 from repro.core.measurement_model import CHIP_IDLE_W
 from repro.core.power_model import occupancy_power
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.serve.engine import Request, ServeEngine
 
 OCC = {"admission": (0.0, 0.05, 0.0), "prefill": (1.0, 0.5, 0.1),
        "decode": (0.15, 1.0, 0.1)}
+LEAD_S = 0.05
+
+
+def timeline_traces(engine, *, seed: int = 0):
+    """Sensor traces of one 4-chip node whose power follows the engine's
+    recorded phases (occupancy model ``OCC``), after ``LEAD_S`` of idle.
+    -> (traces, phases shifted by ``LEAD_S``)."""
+    shifted = [(n, a + LEAD_S, b + LEAD_S)
+               for n, a, b in engine.tracer.phases(depth=0)]
+    watts = {n: {"watts": occupancy_power(*OCC.get(n, (0, 0.1, 0)))}
+             for n, _, _ in shifted}
+    truth = phase_power([("__lead__", 0.0, LEAD_S)] + shifted,
+                        {**watts, "__lead__": {"watts": CHIP_IDLE_W}})
+    traces = NodeFabric(chip_truths=[truth] * 4).sample_all(ToolSpec(),
+                                                            seed=seed)
+    return traces, shifted
 
 
 def main(argv=None):
@@ -29,9 +50,18 @@ def main(argv=None):
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=64)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve a tiny same-family config (CPU runs)")
     args = ap.parse_args(argv)
 
-    cfg = reduce_cfg(get_arch(args.arch))
+    enable_compile_cache()
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = reduce_cfg(cfg)
+    else:
+        # bf16 halves the parameter bytes: a 3B model then leaves most
+        # of one 16 GB chip to the KV cache
+        cfg = dataclasses.replace(cfg, param_dtype="bfloat16")
     model = Model(cfg)
     params = model.init(jax.random.key(0))
     engine = ServeEngine(model, params, batch_slots=args.slots,
@@ -45,15 +75,7 @@ def main(argv=None):
     n_tokens = sum(len(v) for v in results.values())
     print(f"served {len(results)} requests, {n_tokens} tokens")
 
-    phases = engine.tracer.phases(depth=0)
-    lead = 0.05
-    shifted = [(n, a + lead, b + lead) for n, a, b in phases]
-    watts = {n: {"watts": occupancy_power(*OCC.get(n, (0, 0.1, 0)))}
-             for n, _, _ in shifted}
-    truth = phase_power([("__lead__", 0.0, lead)] + shifted,
-                        {**watts, "__lead__": {"watts": CHIP_IDLE_W}})
-    traces = NodeFabric(chip_truths=[truth] * 4).sample_all(ToolSpec(),
-                                                            seed=0)
+    traces, shifted = timeline_traces(engine)
     agg = {}
     for p in attribute_energy(traces["chip0_energy"], shifted):
         a = agg.setdefault(p.phase, [0.0, 0.0])

@@ -117,7 +117,9 @@ def _worker(fn, args, i: int, n: int, port: int, conn):
     logged = False
     try:
         logged = _redirect_to_log(i)
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # the workers test KV-store coordination, not an accelerator:
+        # pinned to the CPU so none of them tries to claim a chip
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import jax
         jax.distributed.initialize(f"127.0.0.1:{port}", num_processes=n,
                                    process_id=i,
@@ -166,8 +168,7 @@ def run_multihost(fn, n_procs: int, *, args=(), timeout: float = 300.0,
     ctx = mp.get_context("spawn")
     if port is None:
         port = free_port()
-    overrides = {"JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
-                 **(env or {})}
+    overrides = {**(env or {}), "JAX_PLATFORMS": "cpu"}
     saved = {k: os.environ.get(k) for k in overrides}
     os.environ.update(overrides)
     procs, conns = [], []

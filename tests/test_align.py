@@ -62,6 +62,37 @@ def test_regrid_kernel_matches_float64_host(mode):
     assert rel.max() <= 1e-5, (mode, rel.max())
 
 
+@pytest.mark.parametrize("mode", ["hold", "linear"])
+def test_grid_resample_kernel_matches_oracle(mode):
+    """The Pallas kernel's column sweep (interpret mode) against the
+    halving-search oracle: identical masks and lower bounds on sorted
+    rows with equal-time runs, a -inf sentinel column, an empty span and
+    a single-sample row.  The kernel only selects samples, so values
+    are bit-identical in both modes."""
+    import jax.numpy as jnp
+    from repro.kernels.grid_resample.ops import GRID_ALIGN, grid_resample
+    rows = _synthetic_rows(k=13, s=300, seed=4)
+    t, v = rows.times.copy(), rows.values.copy()
+    t[0, 0] = -np.inf                              # streaming sentinel
+    t[3, 40:46] = t[3, 40]                         # duplicate publications
+    n = rows.n.reshape(-1, 1).copy()
+    first = rows.first.reshape(-1, 1).copy()
+    n[-1] = 1                                      # one sample
+    first[-2] = n[-2]                              # empty span
+    grid = np.arange(-0.01, 0.4, 7e-4, dtype=np.float32)
+    pad = (-len(grid)) % GRID_ALIGN
+    grid = np.concatenate([grid, np.full(pad, grid[-1], np.float32)])
+    d = np.random.default_rng(6).uniform(
+        -0.01, 0.01, (13, 1)).astype(np.float32)
+    args = [jnp.asarray(a) for a in (t, v, n, first, grid[:, None], d)]
+    vk, mk = grid_resample(*args, mode=mode, interpret=True,
+                           use_kernel=True)
+    # the oracle jitted as the kernel is: XLA fuses both the same way
+    vr, mr = grid_resample(*args, mode=mode, use_kernel=False)
+    np.testing.assert_array_equal(np.asarray(mk), np.asarray(mr))
+    np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
+
+
 def test_regrid_hold_matches_powerseries_resample():
     """The hold convention is PowerSeries.resample, row-batched."""
     rows = _synthetic_rows(k=4, s=150, seed=3)

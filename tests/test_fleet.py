@@ -149,6 +149,32 @@ def test_fleet_kernel_matches_ref():
                                rtol=1e-5, atol=1e-4)
 
 
+def test_fleet_kernel_flags_reordered_rows():
+    """The fused front-end kernel (interpret mode) against its oracle on
+    rows whose timestamps go backwards: identical valid masks, identical
+    ``reordered`` flags (only the reordered rows), identical power."""
+    import jax.numpy as jnp
+    from repro.kernels.power_reconstruct.kernel import \
+        power_reconstruct_fleet_kernel
+    from repro.kernels.power_reconstruct.ref import \
+        reconstruct_power_fleet_ref
+    traces = [_synthetic_trace(f"t{i}", k=150 + 7 * i, seed=20 + i,
+                               wrap_bits=24 if i % 2 else 0,
+                               reorder_at=60 + i if i in (1, 4) else None)
+              for i in range(6)]
+    packed = pack_traces(traces)
+    args = (jnp.asarray(packed.energy), jnp.asarray(packed.times),
+            jnp.asarray(packed.wrap_period)[:, None],
+            jnp.asarray(packed.n_samples)[:, None])
+    pk, vk, rk = power_reconstruct_fleet_kernel(*args, interpret=True)
+    pr, vr, rr = reconstruct_power_fleet_ref(*args)
+    assert vk.dtype == rk.dtype == jnp.bool_
+    np.testing.assert_array_equal(np.asarray(rk), np.asarray(rr))
+    assert np.flatnonzero(np.asarray(rk)[:, 0]).tolist() == [1, 4]
+    np.testing.assert_array_equal(np.asarray(vk), np.asarray(vr))
+    np.testing.assert_array_equal(np.asarray(pk), np.asarray(pr))
+
+
 def test_duplicate_reads_are_masked_not_zero_power():
     """Cached publications must be dropped (masked), not read as 0 W."""
     tr = _synthetic_trace(k=100, seed=3)

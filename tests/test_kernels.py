@@ -117,6 +117,35 @@ def test_fleet_attribute_fused(n, s, p):
                                rtol=1e-4, atol=1e-3)
 
 
+def test_fleet_kernels_pad_ragged_row_counts():
+    """Row counts that are no multiple of the compiled row tile are
+    zero-padded inside the kernels and sliced back (13 rows, 8-row
+    tiles): the same energies as the oracles."""
+    from repro.kernels.fleet_attribute.kernel import fleet_attribute_kernel
+    from repro.kernels.fleet_attribute.ref import fleet_attribute_ref
+    from repro.kernels.phase_integrate.kernel import phase_integrate_kernel
+    rng = np.random.default_rng(13)
+    n, s = 13, 256
+    t = np.cumsum(rng.uniform(0.5e-3, 1.5e-3, (n, s)),
+                  axis=1).astype(np.float32)
+    pw = rng.uniform(50, 250, (n, s)).astype(np.float32)
+    e = np.cumsum(pw * 1e-3, axis=1).astype(np.float32)
+    ph = np.sort(rng.uniform(t.min(), t.max(), (32, 2)).astype(np.float32),
+                 axis=1)
+    args = [jnp.array(a) for a in (t, e, np.zeros((n, 1), np.float32), ph)]
+    out = fleet_attribute_kernel(*args, block_rows=8, interpret=True)
+    assert out.shape == (n, 32)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(fleet_attribute_ref(*args)),
+                               rtol=1e-4, atol=1e-3)
+    out = phase_integrate_kernel(jnp.array(t), jnp.array(pw),
+                                 jnp.array(ph), block_rows=8,
+                                 interpret=True)
+    ref = phase_energies_ref(jnp.array(t), jnp.array(pw), jnp.array(ph))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+
+
 # ------------------------------------------------------------ phase_integrate
 @pytest.mark.parametrize("n,s,p", [(8, 256, 32), (16, 1000, 64)])
 def test_phase_integrate(n, s, p):

@@ -331,8 +331,8 @@ def test_dryrun_single_cell_tiny_mesh():
     """The dry-run machinery itself (lower+compile+costs) on a 2x4 mesh."""
     run_py("""
         import numpy as np, jax
-        devices = jax.devices()      # pin the 8-device backend BEFORE
-        assert len(devices) == 8     # dryrun import rewrites XLA_FLAGS
+        devices = jax.devices()
+        assert len(devices) == 8
         import repro.launch.mesh as mesh_mod
         from jax.sharding import Mesh
         # shrink the production mesh for the 8-device test process

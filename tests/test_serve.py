@@ -292,3 +292,26 @@ def test_poisson_loadgen_seeded_and_shaped():
     assert max(r.max_new_tokens for r in a) >= 22
     c = poisson_requests(40, rate_rps=100.0, seed=8)
     assert [r.arrival_s for r in c] != [r.arrival_s for r in a]
+
+
+def test_serve_cli_reduced_meters_timeline(capsys, monkeypatch, tmp_path):
+    """``launch.serve --reduced`` serves the tiny config and attributes
+    energy on sensor traces synthesized from the engine's timeline: every
+    phase it prints was recorded by the engine, shifted by ``LEAD_S``."""
+    from repro.launch import serve as serve_cli
+    from repro.serve import ServeEngine
+    # a set variable leaves the cache to JAX: main changes no config
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert serve_cli.main(["--reduced", "--requests", "2", "--max-new",
+                        "2"]) == 0
+    out = capsys.readouterr().out
+    assert "served 2 requests, 4 tokens" in out
+    assert "energy per generated token" in out
+    cfg, model, params = _setup()
+    engine = ServeEngine(model, params, batch_slots=2, max_len=32)
+    engine.run(_reqs(cfg, [5, 7], [2, 2]))
+    traces, shifted = serve_cli.timeline_traces(engine)
+    recorded = engine.tracer.phases(depth=0)
+    assert [(n, a + serve_cli.LEAD_S, b + serve_cli.LEAD_S)
+            for n, a, b in recorded] == shifted
+    assert {f"chip{c}_energy" for c in range(4)} <= set(traces)

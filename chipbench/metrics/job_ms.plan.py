@@ -1,0 +1,17 @@
+"""Host milliseconds per fleet job spent planning it: the output grid,
+the carry tail, the window plan and the pipeline's construction (the
+program's ``fleet.plan`` spans over its ``fleet.attribute`` roots, as
+the profiler session of the traced window recorded them)."""
+
+
+def read(ctx):
+    from repro.core import tracing
+    program = getattr(tracing, "PROGRAM", None)
+    if program is None:
+        return None
+    events = list(program.events)
+    roots = sum(e.name == "fleet.attribute" for e in events)
+    if not roots:
+        return None
+    return 1e3 * sum(e.t_end - e.t_start for e in events
+                     if e.name == "fleet.plan") / roots

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.core import tracing
 from repro.core.calibration import apply_corrections
 from repro.fleet.packing import pack_traces, unpack_series
 from repro.fleet.reconstruct import fleet_reconstruct
@@ -45,31 +46,42 @@ def attribute_energy_fleet(traces, phases, *, corrections=None,
     O(fleet × chunk) however long the traces are.
     """
     from repro.core.attribution import PhaseEnergy
-    traces = [apply_corrections(tr, corrections) for tr in traces]
-    if not phases:                       # host-path parity: empty rows
-        return [[] for _ in traces]
-    for tr in traces:
-        assert tr.spec.is_cumulative, \
-            f"{tr.name} is not an energy counter (fleet ΔE/Δt path)"
-    packed = pack_traces(traces, dtype=dtype)
-    # packed times are rebased to the fleet origin; shift windows to match
-    windows = [(a - packed.t0, b - packed.t0) for _, a, b in phases]
-    stream = FleetStream(windows, packed.shape[0],
-                         wrap_period=packed.wrap_period,
-                         dtype=dtype, interpret=interpret,
-                         use_kernel=use_kernel)
-    s = packed.shape[1]
-    for lo in range(0, s, chunk):
-        hi = min(lo + chunk, s)
-        stream.update(packed.times[:, lo:hi], packed.energy[:, lo:hi])
-    totals = stream.totals()
-    out = []
-    for i in range(packed.n_traces):
-        row = []
-        for (name, a, b), e in zip(phases, totals[i]):
-            dur = max(b - a, 1e-12)
-            row.append(PhaseEnergy(name, a, b, float(e), float(e / dur)))
-        out.append(row)
+    n_reads = sum(len(tr) for tr in traces) if tracing.recording() else -1
+    with tracing.span("fleet.attribute", n=n_reads):
+        with tracing.span("fleet.pack"):
+            with tracing.span("fleet.correct"):
+                traces = [apply_corrections(tr, corrections)
+                          for tr in traces]
+            if not phases:                   # host-path parity: empty rows
+                return [[] for _ in traces]
+            for tr in traces:
+                assert tr.spec.is_cumulative, \
+                    f"{tr.name} is not an energy counter (fleet ΔE/Δt path)"
+            packed = pack_traces(traces, dtype=dtype)
+        with tracing.span("fleet.plan"):
+            # packed times are rebased to the fleet origin; shift windows
+            windows = [(a - packed.t0, b - packed.t0) for _, a, b in phases]
+            stream = FleetStream(windows, packed.shape[0],
+                                 wrap_period=packed.wrap_period,
+                                 dtype=dtype, interpret=interpret,
+                                 use_kernel=use_kernel)
+        s = packed.shape[1]
+        for lo in range(0, s, chunk):
+            hi = min(lo + chunk, s)
+            with tracing.span("fleet.window", n=hi - lo):
+                stream.update(packed.times[:, lo:hi],
+                              packed.energy[:, lo:hi])
+        with tracing.span("fleet.totals"):
+            totals = stream.totals()
+        with tracing.span("fleet.rows"):
+            out = []
+            for i in range(packed.n_traces):
+                row = []
+                for (name, a, b), e in zip(phases, totals[i]):
+                    dur = max(b - a, 1e-12)
+                    row.append(PhaseEnergy(name, a, b, float(e),
+                                           float(e / dur)))
+                out.append(row)
     return out
 
 

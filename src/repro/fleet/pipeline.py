@@ -67,12 +67,12 @@ import dataclasses
 import functools
 import logging
 import pickle
-import time
 from pathlib import Path
 
 import jax
 import numpy as np
 
+from repro.core import tracing
 from repro.fleet.config import resolve_config
 from repro.fleet.packing import ROW_ALIGN, _round_up, pack_traces
 from repro.fleet.reconstruct import auto_interpret
@@ -1634,7 +1634,9 @@ class StreamPipeline:
     window count are kept in ``stage_wall_s``/``windows`` (two
     ``perf_counter`` calls per stage per window — noise next to any
     stage's kernel work); ``attach_registry`` exposes them through a
-    ``health.HealthRegistry``.
+    ``health.HealthRegistry``.  While a profiler session records, each
+    stage call is also a ``stage.<StageName>`` program span whose times
+    are the same two clock reads.
     """
 
     def __init__(self, *stages):
@@ -1643,9 +1645,13 @@ class StreamPipeline:
         self.windows = 0
 
     def _timed(self, st, fn, *args):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        self.stage_wall_s[type(st).__name__] += time.perf_counter() - t0
+        name = type(st).__name__
+        with tracing.span("stage." + name) as sp:
+            t0 = tracing.now()
+            out = fn(*args)
+            t1 = tracing.now()
+            sp.clock(t0, t1)
+        self.stage_wall_s[name] += t1 - t0
         return out
 
     def update(self, times, values, valid=None):
@@ -1731,7 +1737,8 @@ def pack_stream_rows(traces, *, corrections=None,
     bit-identical to a single-host pack of the same rows.
     """
     from repro.core.calibration import apply_corrections
-    traces = [apply_corrections(tr, corrections) for tr in traces]
+    with tracing.span("fleet.correct"):
+        traces = [apply_corrections(tr, corrections) for tr in traces]
     assert traces, "pack_stream_rows needs at least one trace"
     if t0 is None:
         t0 = min(float((tr.t_measured if use_t_measured
@@ -1869,17 +1876,24 @@ def stream_row_windows(rows: StreamRows, chunk: int = 1024, *,
     n_win, idx = _replay_window_plan(rows, chunk, span=span,
                                      cadence=cadence)
     for w in range(n_win):
-        lo, hi = idx[:, w], idx[:, w + 1]
-        cnt = hi - lo
-        width = int(cnt.max())
-        width = max(_round_up(width, 64), 64)
-        cols = lo[:, None] + np.arange(width)[None, :]
-        # rows short of the window replicate their last in-window
-        # sample; rows with no new samples replicate their previous one
-        cols = np.minimum(cols, np.maximum(hi - 1, np.maximum(lo - 1,
-                                                              0))[:, None])
-        yield (np.take_along_axis(rows.times, cols, axis=1),
-               np.take_along_axis(rows.values, cols, axis=1))
+        yield _replay_window(rows, idx, w)
+
+
+def _window_width(idx, w: int) -> int:
+    """Columns of replay window ``w``: its widest row, rounded up to 64."""
+    return max(_round_up(int((idx[:, w + 1] - idx[:, w]).max()), 64), 64)
+
+
+def _replay_window(rows: StreamRows, idx, w: int):
+    """(times, values) block of replay window ``w`` of the plan ``idx``."""
+    lo, hi = idx[:, w], idx[:, w + 1]
+    cols = lo[:, None] + np.arange(_window_width(idx, w))[None, :]
+    # rows short of the window replicate their last in-window
+    # sample; rows with no new samples replicate their previous one
+    cols = np.minimum(cols, np.maximum(hi - 1, np.maximum(lo - 1,
+                                                          0))[:, None])
+    return (np.take_along_axis(rows.times, cols, axis=1),
+            np.take_along_axis(rows.values, cols, axis=1))
 
 
 class StreamingFusedPipeline:
@@ -3154,90 +3168,108 @@ def attribute_energy_fused_streaming(trace_groups, phases, *,
     health, dq_policy = cfg.health, cfg.dq
     groups = [list(g) for g in trace_groups]
     flat = [tr for g in groups for tr in g]
-    rows = pack_stream_rows(flat, corrections=corrections,
-                            use_t_measured=use_t_measured, dtype=dtype)
-    if grid is not None:
-        grid = np.asarray(grid, np.float64)
-        grid_step = float(np.median(np.diff(grid)))
-        origin = float(grid[0]) - rows.t0
-        t_end = float(grid[-1]) - rows.t0
-    else:
-        if grid_step is None:
-            grid_step = 0.5 * _min_cadence(rows)
-        origin = float(rows.times[:rows.n_streams, 0]
-                       .astype(np.float64).min())
-        t_end = None
-    if tail is None and engine == "windowed":
-        # the scan engine has no carry tail — don't pay the cadence scan
-        tail = default_tail(rows, chunk, delays=delays,
-                            max_lag=max_lag, grid_step=grid_step)
-    ref = None
-    if reference is not None:
-        from repro.core.power_model import PiecewisePower
-        if isinstance(reference, PiecewisePower):
-            t0 = rows.t0
-            ref = lambda t, _r=reference: _r.power_at(t + t0)  # noqa: E731
+    n_reads = sum(len(tr) for tr in flat) if tracing.recording() else -1
+    with tracing.span("fleet.attribute", n=n_reads):
+        with tracing.span("fleet.pack"):
+            rows = pack_stream_rows(flat, corrections=corrections,
+                                    use_t_measured=use_t_measured,
+                                    dtype=dtype)
+        with tracing.span("fleet.plan"):
+            if grid is not None:
+                grid = np.asarray(grid, np.float64)
+                grid_step = float(np.median(np.diff(grid)))
+                origin = float(grid[0]) - rows.t0
+                t_end = float(grid[-1]) - rows.t0
+            else:
+                if grid_step is None:
+                    grid_step = 0.5 * _min_cadence(rows)
+                origin = float(rows.times[:rows.n_streams, 0]
+                               .astype(np.float64).min())
+                t_end = None
+            if tail is None and engine == "windowed":
+                # the scan engine has no carry tail — don't pay the
+                # cadence scan
+                tail = default_tail(rows, chunk, delays=delays,
+                                    max_lag=max_lag, grid_step=grid_step)
+            ref = None
+            if reference is not None:
+                from repro.core.power_model import PiecewisePower
+                if isinstance(reference, PiecewisePower):
+                    t0 = rows.t0
+                    ref = (lambda t, _r=reference:  # noqa: E731
+                           _r.power_at(t + t0))
+                else:
+                    ref = reference
+            if not phases:
+                return [[] for _ in groups]
+            windows = [(a - rows.t0, b - rows.t0) for _, a, b in phases]
+            assert engine in ("windowed", "scan"), engine
+            if health:
+                assert engine == "windowed", \
+                    "the health stage composes with the windowed engine only"
+            if meter:
+                assert engine == "windowed", \
+                    "the metering stage composes with the windowed " \
+                    "engine only"
+                meter = [s.shifted(-rows.t0) for s in meter]
+            if checkpoint_dir is not None or resume or on_window is not None:
+                assert engine == "windowed", \
+                    "checkpointing drives the windowed engine only"
+            if engine == "windowed":
+                pipe = StreamingFusedPipeline(
+                    [len(g) for g in groups], windows, grid_origin=origin,
+                    grid_step=grid_step, kind_row=rows.kind_row,
+                    delays=delays, reference=ref, track=track,
+                    window=window, hop=hop, max_lag=max_lag, ema=ema,
+                    tail=tail, var_floor=var_floor, dtype=dtype,
+                    interpret=interpret, use_kernel=use_kernel, host=host,
+                    health=health, registry=registry,
+                    health_names=[tr.name for tr in flat], meter=meter,
+                    dq_policy=dq_policy)
+                n_win, idx = _replay_window_plan(rows, chunk)
+        if engine == "scan":
+            assert not return_pipe, "return_pipe needs the windowed engine"
+            with tracing.span("fleet.scan", n=rows.shape[1]):
+                res = attribute_totals_fused_scan(
+                    rows, [len(g) for g in groups], windows,
+                    grid_origin=origin, grid_step=grid_step, t_end=t_end,
+                    chunk=chunk, delays=delays, reference=ref, track=track,
+                    window=window, hop=hop, max_lag=max_lag, ema=ema,
+                    var_floor=var_floor, interpret=interpret,
+                    use_kernel=use_kernel, host=host)
+            totals = res.totals
+            pipe = None
         else:
-            ref = reference
-    if not phases:
-        return [[] for _ in groups]
-    windows = [(a - rows.t0, b - rows.t0) for _, a, b in phases]
-    assert engine in ("windowed", "scan"), engine
-    if health:
-        assert engine == "windowed", \
-            "the health stage composes with the windowed engine only"
-    if meter:
-        assert engine == "windowed", \
-            "the metering stage composes with the windowed engine only"
-        meter = [s.shifted(-rows.t0) for s in meter]
-    if checkpoint_dir is not None or resume or on_window is not None:
-        assert engine == "windowed", \
-            "checkpointing drives the windowed engine only"
-    if engine == "scan":
-        assert not return_pipe, "return_pipe needs the windowed engine"
-        res = attribute_totals_fused_scan(
-            rows, [len(g) for g in groups], windows, grid_origin=origin,
-            grid_step=grid_step, t_end=t_end, chunk=chunk, delays=delays,
-            reference=ref, track=track, window=window, hop=hop,
-            max_lag=max_lag, ema=ema, var_floor=var_floor,
-            interpret=interpret, use_kernel=use_kernel, host=host)
-        totals = res.totals
-        pipe = None
-    else:
-        pipe = StreamingFusedPipeline(
-            [len(g) for g in groups], windows, grid_origin=origin,
-            grid_step=grid_step, kind_row=rows.kind_row, delays=delays,
-            reference=ref, track=track, window=window, hop=hop,
-            max_lag=max_lag, ema=ema, tail=tail, var_floor=var_floor,
-            dtype=dtype, interpret=interpret, use_kernel=use_kernel,
-            host=host, health=health, registry=registry,
-            health_names=[tr.name for tr in flat], meter=meter,
-            dq_policy=dq_policy)
-        start_w = 0
-        if resume:
-            assert checkpoint_dir is not None, \
-                "resume=True needs checkpoint_dir"
-            try:
-                start_w = pipe.restore(checkpoint_dir)
-            except FileNotFoundError:
-                start_w = 0          # cold start: nothing published yet
-        for w, (t_blk, v_blk) in enumerate(
-                stream_row_windows(rows, chunk), start=1):
-            if w <= start_w:
-                continue             # replayed windows: already folded
-            pipe.update(t_blk, v_blk)
-            if (checkpoint_dir is not None and checkpoint_every
-                    and w % checkpoint_every == 0):
-                pipe.checkpoint(checkpoint_dir)
-            if on_window is not None:
-                on_window(pipe, w)
-        pipe.finalize(t_end)
-        totals = pipe.totals()
-    out = []
-    for di in range(len(groups)):
-        row = []
-        for (name, a, b), e in zip(phases, totals[di]):
-            dur = max(b - a, 1e-12)
-            row.append(PhaseEnergy(name, a, b, float(e), float(e / dur)))
-        out.append(row)
+            start_w = 0
+            if resume:
+                assert checkpoint_dir is not None, \
+                    "resume=True needs checkpoint_dir"
+                try:
+                    start_w = pipe.restore(checkpoint_dir)
+                except FileNotFoundError:
+                    start_w = 0      # cold start: nothing published yet
+            # windows up to start_w were folded before the checkpoint
+            for w in range(start_w + 1, n_win + 1):
+                with tracing.span("fleet.window",
+                                  n=_window_width(idx, w - 1)):
+                    t_blk, v_blk = _replay_window(rows, idx, w - 1)
+                    pipe.update(t_blk, v_blk)
+                    if (checkpoint_dir is not None and checkpoint_every
+                            and w % checkpoint_every == 0):
+                        pipe.checkpoint(checkpoint_dir)
+                    if on_window is not None:
+                        on_window(pipe, w)
+            with tracing.span("fleet.finalize"):
+                pipe.finalize(t_end)
+            with tracing.span("fleet.totals"):
+                totals = pipe.totals()
+        with tracing.span("fleet.rows"):
+            out = []
+            for di in range(len(groups)):
+                row = []
+                for (name, a, b), e in zip(phases, totals[di]):
+                    dur = max(b - a, 1e-12)
+                    row.append(PhaseEnergy(name, a, b, float(e),
+                                           float(e / dur)))
+                out.append(row)
     return (out, pipe) if return_pipe else out

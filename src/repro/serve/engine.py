@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import tracing
 from repro.core.tracing import RegionTracer
 from repro.fleet.pipeline import SlotSegment
 from repro.models import Model
@@ -64,6 +65,15 @@ class Request:
         return self.t_done - self.t_arrival
 
 
+def _jit(name: str, fn, **kw):
+    """``jax.jit(fn)`` under a stable program name: its XLA module, and
+    so the trace's ``XLA Modules`` line, reads ``jit_<name>``."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return jax.jit(program, **kw)
+
+
 def _make_masked_step(model: Model):
     """One jitted decode step over ALL slots: per-slot positions,
     inactive slots pinned to token 0 at position 0 (their cache rows
@@ -82,7 +92,7 @@ def _make_masked_step(model: Model):
         buf = buf.at[:, w].set(nxt)
         return nxt, cache, buf
 
-    return jax.jit(step, donate_argnums=(1, 5))
+    return _jit("serve_decode_step", step, donate_argnums=(1, 5))
 
 
 def _scatter_slot(big, small, slot):
@@ -239,10 +249,12 @@ class ServeEngine(_AttributionMixin):
         # persistent slot-batched cache — allocated ONCE, reused across
         # requests (admission rewrites one slot row)
         self.cache = model.init_cache(self.slots, self.max_len)
-        self._prefill = jax.jit(model.prefill)
+        self._prefill = _jit("serve_prefill", model.prefill)
         self._step = _make_masked_step(model)
-        self._admit_slot = jax.jit(_scatter_slot, donate_argnums=(0,))
-        self._zeros1 = jax.jit(lambda: model.init_cache(1, self.max_len))
+        self._admit_slot = _jit("serve_scatter_slot", _scatter_slot,
+                                donate_argnums=(0,))
+        self._zeros1 = _jit("serve_zero_cache",
+                            lambda: model.init_cache(1, self.max_len))
         self._nxt = jnp.zeros((self.slots,), jnp.int32)
         self._pend = jnp.zeros((self.slots,), jnp.int32)
         self._buf = jnp.zeros((self.slots, self.flush_interval),
@@ -323,8 +335,9 @@ class ServeEngine(_AttributionMixin):
                 self.params, self.cache, tok, posd, act, buf,
                 jnp.asarray(t, jnp.int32))
         self._nxt, self._buf = tok, buf
-        toks = self._to_host(
-            jnp.concatenate([self._pend[:, None], buf], axis=1))
+        with tracing.span("serve.drain"):
+            toks = self._to_host(
+                jnp.concatenate([self._pend[:, None], buf], axis=1))
         t1 = self.tracer.now()
         if k:
             self.tracer.add_region("decode", t0, t1, depth=0)
@@ -364,6 +377,12 @@ class ServeEngine(_AttributionMixin):
         """
         results: dict = {}
         reqs = list(requests)
+        with tracing.span("serve.run", n=len(reqs)):
+            self._serve(reqs, results, respect_arrivals)
+        return results
+
+    def _serve(self, reqs, results, respect_arrivals):
+        """``run``'s scheduler loop: admit, decode, evict until done."""
         t_run0 = self.tracer.now()
         for r in reqs:
             r.t_arrival = t_run0 + (r.arrival_s if respect_arrivals
@@ -385,7 +404,8 @@ class ServeEngine(_AttributionMixin):
                 if respect_arrivals and r.t_arrival > self.tracer.now():
                     if active.any():
                         break           # keep decoding while we wait
-                    self._idle_until(r.t_arrival)
+                    with tracing.span("serve.wait"):
+                        self._idle_until(r.t_arrival)
                 queue.popleft()
                 if r.max_new_tokens <= 0:
                     r.done = True
@@ -393,7 +413,12 @@ class ServeEngine(_AttributionMixin):
                     continue
                 i = free[fi]
                 fi += 1
-                lb = self._admit(i, r)
+                with tracing.span("serve.admit", rid=r.rid, slot=i) as adm:
+                    lb = self._admit(i, r)
+                # the request's wait for a slot, known once it is admitted
+                tracing.add_span("serve.queued", adm.t_start
+                                 - (r.t_admitted - r.t_arrival),
+                                 adm.t_start, rid=r.rid)
                 slot_req[i] = r
                 pos[i] = lb
                 remaining[i] = r.max_new_tokens - 1   # 1 pending token
@@ -404,12 +429,13 @@ class ServeEngine(_AttributionMixin):
             if not active.any():
                 continue
             k = int(min(self.flush_interval, remaining[active].min()))
-            self._decode_segment(k, slot_req, pos, remaining, active,
-                                 pend_fresh, results)
+            with tracing.span("serve.decode", n=k,
+                              active=self.active_slots):
+                self._decode_segment(k, slot_req, pos, remaining, active,
+                                     pend_fresh, results)
             self.active_slots = int(active.sum())
         self.queue_depth = 0
         self.active_slots = 0
-        return results
 
     # -- per-request energy ----------------------------------------------
 

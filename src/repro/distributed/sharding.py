@@ -141,7 +141,7 @@ class ShardingPlan:
                 return NamedSharding(self.mesh, P(*fixed))
 
             if name in ("k", "v", "cross_k", "cross_v"):
-                return ns(None, dp, "model")          # (G,B,S,kv,h): seq
+                return ns(None, dp, None, None, "model")  # (G,B,kv,h,S): seq
             if name == "ssm":
                 return ns(None, dp, "model")          # (G,B,d_in,N)
             if name == "conv":

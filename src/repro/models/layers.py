@@ -14,6 +14,8 @@ and the CPU/dry-run path.
 from __future__ import annotations
 
 import dataclasses
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -196,19 +198,62 @@ def attention_specs(cfg, *, cross=False, prefix=""):
     return specs
 
 
+def to_cache_layout(t):
+    """(B, S, Hkv, D) keys or values -> the KV-cache layout (B, Hkv, D, S).
+
+    The sequence is the minor (lane) axis: a 64-wide head dim is not
+    padded to 128 lanes, and decode attention reduces over D for the
+    scores and over S for the output straight from this layout, so the
+    compiler inserts no conversion of the cache."""
+    return jnp.moveaxis(t, 1, -1)
+
+
+def _write_kv_rows(stacked, layer, rows, slot):
+    """Write one (B, Hkv, D) row per batch entry into column ``slot`` of
+    layer ``layer`` of a stacked (G, B, Hkv, D, W) cache, in place.
+    ``slot``: scalar, or (B,) when every row has its own position.
+
+    Each row goes in through the aligned block of up to 128 positions
+    (one lane tile) that holds its column: the block is read, the column
+    replaced and the block written back.  A write one position wide on
+    the minor axis would make the compiler keep the whole cache in a
+    layout with D on the lanes, padded to 128, and convert it at the
+    program's boundary."""
+    blk = math.gcd(stacked.shape[-1], 128)
+    lane = jnp.arange(blk)
+
+    def put(c, i0, r, pos):
+        b0 = pos // blk * blk
+        start = (layer, i0, 0, 0, b0)
+        old = lax.dynamic_slice(c, start, (1,) + r.shape + (blk,))
+        new = jnp.where(lane == pos - b0, r[None, ..., None], old)
+        return lax.dynamic_update_slice(c, new, start)
+
+    if jnp.ndim(slot) == 0:
+        return put(stacked, 0, rows, slot)
+    for i in range(rows.shape[0]):
+        stacked = put(stacked, i, rows[i:i + 1], slot[i])
+    return stacked
+
+
 def attention_apply(p, cfg, x, positions, *, layer_window=0, kv_cache=None,
-                    cache_index=None, cross_kv=None, causal=True,
-                    mesh=None):
+                    layer=None, cache_index=None, cross_kv=None,
+                    causal=True, mesh=None):
     """Returns (out, new_kv_cache).
 
-    kv_cache: dict(k=(B, W, Hkv, D), v=...) or None.  For sliding-window
-    layers W = min(max_len, window) and the cache is a RING indexed by
+    kv_cache: dict(k=(G, B, Hkv, D, W), v=...), the stacked cache of
+    every layer at this pattern position in the cache layout
+    (:func:`to_cache_layout`), or None; ``layer`` indexes it, and only
+    that layer's new entries are written.  For sliding-window layers
+    W = min(max_len, window) and the cache is a RING indexed by
     position % W; otherwise W = max_len with direct indexing.
     cache_index: scalar int32 — write offset (decode) / 0 (prefill) —
     or a (B,) int32 vector of per-row offsets during single-token decode
     (continuous batching: each slot advances at its own position).
-    cross_kv: precomputed (k, v) for cross-attention (whisper decoder).
+    cross_kv: precomputed (k, v) for cross-attention (whisper decoder),
+    each (B, Hkv, D, F) in the cache layout.
     """
+    from repro.distributed.decode_attention import decode_attention
     b, s, _ = x.shape
     h = cfg.resolved_head_dim
     nq, nkv = cfg.num_heads, cfg.num_kv_heads
@@ -237,7 +282,11 @@ def attention_apply(p, cfg, x, positions, *, layer_window=0, kv_cache=None,
         q = q + p["bq"].astype(dt).reshape(nq, h)
     if cross_kv is not None:
         k, v = cross_kv
-        out = attention(q, k, v, causal=False)
+        if s == 1:          # decode: every frame is valid
+            out = decode_attention(q, k, v, k.shape[-1] - 1, mesh)
+        else:
+            out = attention(q, jnp.moveaxis(k, -1, 1),
+                            jnp.moveaxis(v, -1, 1), causal=False)
         out = out.reshape(b, s, nq * h)
         return out @ p["wo"].astype(dt), kv_cache
 
@@ -259,53 +308,41 @@ def attention_apply(p, cfg, x, positions, *, layer_window=0, kv_cache=None,
         out = out.reshape(b, s, nq * h)
         return out @ p["wo"].astype(dt), None
 
-    w_len = kv_cache["k"].shape[1]
+    w_len = kv_cache["k"].shape[-1]
     ring = bool(layer_window) and w_len <= layer_window
     cd = kv_cache["k"].dtype
     if s > 1:
-        # prefill: attend over the fresh k/v, then write the cache
+        # prefill: attend over the fresh k/v, then write the layer's cache
         out = attention(q, k, v, causal=True, window=layer_window,
                         logit_cap=cfg.logit_softcap)
+        start = cache_index
         if ring:
+            start = 0
             if s >= w_len:
                 # position p lives at slot p % W -> rolled last-W block
                 r = (s - w_len) % w_len
-                kw = jnp.roll(k[:, s - w_len:], r, axis=1)
-                vw = jnp.roll(v[:, s - w_len:], r, axis=1)
-            else:
-                kw, vw = k, v
-            ck = lax.dynamic_update_slice(
-                kv_cache["k"], kw.astype(cd), (0, 0, 0, 0))
-            cv = lax.dynamic_update_slice(
-                kv_cache["v"], vw.astype(cd), (0, 0, 0, 0))
-        else:
-            ck = lax.dynamic_update_slice(
-                kv_cache["k"], k.astype(cd), (0, cache_index, 0, 0))
-            cv = lax.dynamic_update_slice(
-                kv_cache["v"], v.astype(cd), (0, cache_index, 0, 0))
-        return (out.reshape(b, s, nq * h) @ p["wo"].astype(dt),
-                {"k": ck, "v": cv})
+                k = jnp.roll(k[:, s - w_len:], r, axis=1)
+                v = jnp.roll(v[:, s - w_len:], r, axis=1)
+        new = {n: lax.dynamic_update_slice(
+                   kv_cache[n], to_cache_layout(t).astype(cd)[None],
+                   (layer, 0, 0, 0, start))
+               for n, t in (("k", k), ("v", v))}
+        return out.reshape(b, s, nq * h) @ p["wo"].astype(dt), new
 
-    # decode: ring slot or direct slot, then distributed flash-decode
+    # decode: one row per slot into the ring or direct slot, then
+    # distributed flash-decode over the layer, read in the cache layout
     # (caches stay in their storage dtype; dequant happens per shard)
     slot = jnp.mod(cache_index, w_len) if ring else cache_index
-    if jnp.ndim(slot) == 1:
-        # per-row write offsets: scatter each batch row at its own slot
-        ck = kv_cache["k"].at[jnp.arange(b), slot].set(k[:, 0].astype(cd))
-        cv = kv_cache["v"].at[jnp.arange(b), slot].set(v[:, 0].astype(cd))
-    else:
-        ck = lax.dynamic_update_slice(kv_cache["k"], k.astype(cd),
-                                      (0, slot, 0, 0))
-        cv = lax.dynamic_update_slice(kv_cache["v"], v.astype(cd),
-                                      (0, slot, 0, 0))
-    from repro.distributed.decode_attention import decode_attention
+    new = {n: _write_kv_rows(kv_cache[n], layer, t[:, 0].astype(cd), slot)
+           for n, t in (("k", k), ("v", v))}
+    ck, cv = (lax.dynamic_index_in_dim(new[n], layer, keepdims=False)
+              for n in ("k", "v"))
     out = decode_attention(
         q, ck, cv, cache_index, mesh,
         window=0 if ring else layer_window,     # ring bounds the window
         logit_cap=cfg.logit_softcap)
     out = out.astype(dt)
-    return (out.reshape(b, s, nq * h) @ p["wo"].astype(dt),
-            {"k": ck, "v": cv})
+    return out.reshape(b, s, nq * h) @ p["wo"].astype(dt), new
 
 
 # ---------------------------------------------------------------------------

@@ -67,6 +67,19 @@ def _stack_specs(specs, n):
         specs, is_leaf=lambda x: isinstance(x, ParamSpec))
 
 
+def _layer(tree, layer):
+    """Layer ``layer``'s entries of a stacked cache pytree."""
+    return jax.tree.map(
+        lambda a: lax.dynamic_index_in_dim(a, layer, keepdims=False), tree)
+
+
+def _set_layer(tree, new, layer):
+    """Write ``new`` as layer ``layer``'s entries of a stacked cache."""
+    return jax.tree.map(
+        lambda a, n: lax.dynamic_update_index_in_dim(
+            a, n.astype(a.dtype), layer, 0), tree, new)
+
+
 class Model:
     def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
@@ -118,61 +131,64 @@ class Model:
     # ------------------------------------------------------------------
     # Block application
     # ------------------------------------------------------------------
-    def _apply_block(self, kind, p, x, positions, *, layer_pos, cache=None,
-                     cache_index=None, enc_out=None, causal=True):
+    def _apply_block(self, kind, p, x, positions, *, layer_pos, layer=None,
+                     cache=None, cache_index=None, enc_out=None,
+                     causal=True):
+        """One block.  ``cache``: this pattern position's stacked cache
+        (every layer's entries on a leading axis) or None; the block
+        reads and writes only layer ``layer``'s entries and returns the
+        whole stacked cache, so the buffer is updated in place."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
-        new_cache = {}
+        new_cache = dict(cache) if cache is not None else {}
         h = L.rms_norm(x, p["norm1"], cfg.rms_eps)
         if kind in (ATTN, ATTN_LOCAL):
             window = cfg.sliding_window if kind == ATTN_LOCAL else 0
-            kvc = cache.get("kv") if cache else None
             out, nkv = L.attention_apply(
                 p["core"], cfg, h, positions, layer_window=window,
-                kv_cache=kvc, cache_index=cache_index, causal=causal,
-                mesh=self.mesh)
+                kv_cache=new_cache.get("kv"), layer=layer,
+                cache_index=cache_index, causal=causal, mesh=self.mesh)
             if nkv is not None:
                 new_cache["kv"] = nkv
         elif kind == MAMBA:
+            mc = ({n: cache[n] for n in ("ssm", "conv")}
+                  if cache is not None else None)
+            st = _layer(mc, layer) if mc is not None else {}
             out, st = M.mamba_apply(
-                p["core"], cfg, h,
-                ssm_state=cache.get("ssm") if cache else None,
-                conv_state=cache.get("conv") if cache else None)
+                p["core"], cfg, h, ssm_state=st.get("ssm"),
+                conv_state=st.get("conv"))
+            if mc is not None:
+                new_cache.update(_set_layer(mc, st, layer))
+        elif kind in (MLSTM, SLSTM):
+            name = "mlstm" if kind == MLSTM else "slstm"
+            apply = X.mlstm_apply if kind == MLSTM else X.slstm_apply
+            st = _layer(cache[name], layer) if cache is not None else None
+            out, st = apply(p["core"], cfg, h, state=st)
             if cache is not None:
-                new_cache.update(st)
-        elif kind == MLSTM:
-            out, st = X.mlstm_apply(
-                p["core"], cfg, h,
-                state=cache.get("mlstm") if cache else None)
-            if cache is not None:
-                new_cache["mlstm"] = st
-        elif kind == SLSTM:
-            out, st = X.slstm_apply(
-                p["core"], cfg, h,
-                state=cache.get("slstm") if cache else None)
-            if cache is not None:
-                new_cache["slstm"] = st
+                new_cache[name] = _set_layer(cache[name], st, layer)
         x = x + out
 
         has_cached_cross = cache is not None and "cross_k" in cache
         if "cross" in p and (enc_out is not None or has_cached_cross):
             hc = L.rms_norm(x, p["cross_norm"], cfg.rms_eps)
             dt = hc.dtype
-            ck = None
-            if has_cached_cross and enc_out is None:
-                ck = cache["cross_k"]
-            if ck is None:
+            if enc_out is not None:
                 b, f, _ = enc_out.shape
-                ck = (enc_out @ p["cross"]["wk"].astype(dt)).reshape(
-                    b, f, cfg.num_kv_heads, cfg.resolved_head_dim)
-                cv = (enc_out @ p["cross"]["wv"].astype(dt)).reshape(
-                    b, f, cfg.num_kv_heads, cfg.resolved_head_dim)
+                ck, cv = (L.to_cache_layout(
+                    (enc_out @ p["cross"][w].astype(dt)).reshape(
+                        b, f, cfg.num_kv_heads, cfg.resolved_head_dim))
+                    for w in ("wk", "wv"))
+                if cache is not None:
+                    new_cache["cross_k"] = _set_layer(cache["cross_k"], ck,
+                                                      layer)
+                    new_cache["cross_v"] = _set_layer(cache["cross_v"], cv,
+                                                      layer)
             else:
-                cv = cache["cross_v"]
+                ck = _layer(cache["cross_k"], layer)
+                cv = _layer(cache["cross_v"], layer)
             out, _ = L.attention_apply(p["cross"], cfg, hc, positions,
-                                       cross_kv=(ck.astype(dt), cv.astype(dt)))
-            if cache is not None:
-                new_cache["cross_k"], new_cache["cross_v"] = ck, cv
+                                       cross_kv=(ck.astype(dt), cv.astype(dt)),
+                                       mesh=self.mesh)
             x = x + out
 
         if "ffn" in p:
@@ -224,19 +240,21 @@ class Model:
                 if w.ndim >= 3 else w, stacked_params)
 
         def body(carry, scan_in):
-            xc, aux_sum = carry
-            pg, cg = scan_in
-            new_cg = {}
+            # the caches ride in the carry: each block writes its layer's
+            # new entries into the stacked buffers in place
+            xc, aux_sum, cc = carry
+            pg, layer = scan_in
             for p_idx, kind in enumerate(pattern):
                 key = f"pos{p_idx}"
-                bc = cg[key] if cg is not None else None
                 xc, nc, aux = self._apply_block(
                     kind, pg[key], xc, positions, layer_pos=p_idx,
-                    cache=bc, cache_index=cache_index, enc_out=enc_out)
+                    layer=layer, cache=cc[key] if cc is not None else None,
+                    cache_index=cache_index, enc_out=enc_out)
                 xc = self._constrain_act(xc)
-                new_cg[key] = nc
+                if cc is not None:
+                    cc = {**cc, key: nc}
                 aux_sum = aux_sum + aux
-            return (xc, aux_sum), new_cg
+            return (xc, aux_sum, cc), None
 
         if remat:
             import os
@@ -245,9 +263,10 @@ class Model:
                       if pol == "dots"
                       else jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(body, policy=policy)
-        (x, aux), new_caches = lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), (stacked_params, caches))
-        return x, aux, new_caches
+        (x, aux, caches), _ = lax.scan(
+            body, (x, jnp.zeros((), jnp.float32), caches),
+            (stacked_params, jnp.arange(self.n_groups)))
+        return x, aux, caches
 
     # ------------------------------------------------------------------
     # Embedding / unembedding
@@ -366,11 +385,12 @@ class Model:
                 eff = max_len
                 if kind == ATTN_LOCAL and cfg.sliding_window:
                     eff = min(max_len, cfg.sliding_window)
+                # layers.to_cache_layout: the sequence on the minor axis
                 c["kv"] = {
                     "k": jax.ShapeDtypeStruct(
-                        (g, batch_size, eff, nkv, h), cd),
+                        (g, batch_size, nkv, h, eff), cd),
                     "v": jax.ShapeDtypeStruct(
-                        (g, batch_size, eff, nkv, h), cd),
+                        (g, batch_size, nkv, h, eff), cd),
                 }
             elif kind == MAMBA:
                 st = M.mamba_state_specs(cfg, batch_size)
@@ -387,9 +407,9 @@ class Model:
             if cfg.encoder_layers:
                 f = cfg.num_audio_frames
                 c["cross_k"] = jax.ShapeDtypeStruct(
-                    (g, batch_size, f, nkv, h), cd)
+                    (g, batch_size, nkv, h, f), cd)
                 c["cross_v"] = jax.ShapeDtypeStruct(
-                    (g, batch_size, f, nkv, h), cd)
+                    (g, batch_size, nkv, h, f), cd)
             caches[f"pos{p_idx}"] = c
         return caches
 

@@ -249,7 +249,9 @@ class ServeEngine(_AttributionMixin):
         # persistent slot-batched cache — allocated ONCE, reused across
         # requests (admission rewrites one slot row)
         self.cache = model.init_cache(self.slots, self.max_len)
-        self._prefill = _jit("serve_prefill", model.prefill)
+        # prefill writes its batch-1 scratch cache in place
+        self._prefill = _jit("serve_prefill", model.prefill,
+                             donate_argnums=(2,))
         self._step = _make_masked_step(model)
         self._admit_slot = _jit("serve_scatter_slot", _scatter_slot,
                                 donate_argnums=(0,))

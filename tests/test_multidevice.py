@@ -31,8 +31,9 @@ def test_decode_attention_sharded_matches_oracle():
         B, S, HQ, HKV, D = 4, 64, 8, 4, 32
         ks = jax.random.split(jax.random.key(0), 3)
         q = jax.random.normal(ks[0], (B, 1, HQ, D), jnp.float32)
-        ck = jax.random.normal(ks[1], (B, S, HKV, D), jnp.float32)
-        cv = jax.random.normal(ks[2], (B, S, HKV, D), jnp.float32)
+        # the cache layout (B, Hkv, D, S): the sequence is the last axis
+        ck = jax.random.normal(ks[1], (B, HKV, D, S), jnp.float32)
+        cv = jax.random.normal(ks[2], (B, HKV, D, S), jnp.float32)
         pos = jnp.asarray(40, jnp.int32)
         with mesh:
             out = jax.jit(lambda q, k, v: decode_attention(
@@ -49,6 +50,48 @@ def test_decode_attention_sharded_matches_oracle():
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
         print("decode_attention sharded OK")
+    """)
+
+
+def test_decode_step_seq_sharded_cache_matches_single_device():
+    """Masked decode at per-slot positions with the stacked slot cache
+    batch-sharded on "data" and seq-sharded on "model" (its last axis):
+    the same logits as one device."""
+    run_py("""
+        import dataclasses, jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh
+        from repro.configs import get_arch, reduced
+        from repro.distributed.sharding import make_plan
+        from repro.models import Model
+        mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
+                    ("data", "model"))
+        plan = make_plan(mesh, 0)
+        B, S = 4, 32
+        for arch in ("llama3.2-3b", "gemma2-27b"):
+            cfg = dataclasses.replace(reduced(get_arch(arch)),
+                                      compute_dtype="float32")
+            params = Model(cfg).init(jax.random.key(0))
+            toks = jax.random.randint(jax.random.key(1), (B, 6), 1,
+                                      cfg.vocab_size)
+            out = []
+            for m in (Model(cfg), Model(cfg)):
+                cache = m.init_cache(B, S)
+                if out:
+                    m.mesh = mesh
+                    cache = jax.device_put(
+                        cache, plan.cache_shardings(m.cache_specs(B, S), B))
+                    assert cache["pos0"]["kv"]["k"].sharding.spec[4] \
+                        == "model"
+                step = jax.jit(m.decode_step)
+                for t in range(6):
+                    cur = jnp.arange(B, dtype=jnp.int32) + t
+                    with mesh:
+                        lg, cache = step(params, {
+                            "tokens": toks[:, t:t + 1],
+                            "positions": cur[:, None]}, cache, cur)
+                out.append(np.asarray(lg))
+            np.testing.assert_allclose(out[1], out[0], rtol=1e-5, atol=1e-5)
+        print("seq-sharded decode OK")
     """)
 
 

@@ -98,6 +98,7 @@ def test_ring_cache_bounds_local_layer_memory():
     cfg = reduced(get_arch("gemma2-27b"))
     model = Model(cfg)
     specs = model.cache_specs(2, 32)
-    # pattern = (local, global): pos0 ring-bounded by window, pos1 full
-    assert specs["pos0"]["kv"]["k"].shape[2] == cfg.sliding_window
-    assert specs["pos1"]["kv"]["k"].shape[2] == 32
+    # pattern = (local, global): pos0 ring-bounded by window, pos1 full;
+    # the sequence is the cache layout's last axis (G, B, Hkv, D, W)
+    assert specs["pos0"]["kv"]["k"].shape[4] == cfg.sliding_window
+    assert specs["pos1"]["kv"]["k"].shape[4] == 32

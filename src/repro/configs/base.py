@@ -31,8 +31,38 @@ class MoEConfig:
     num_shared_experts: int = 0
     router_aux_weight: float = 0.01
     router_z_weight: float = 1e-3
-    # capacity factor for dispatch buffers (train); decode uses dense gather
+    # capacity factor for dispatch buffers (train); serving drops no token
     capacity_factor: float = 1.25
+    # --- router semantics: the chosen gates are renormalised, then scaled
+    scoring: str = "softmax"        # softmax | sigmoid (DeepSeek-V3 style)
+    selection_bias: bool = False    # per-expert bias added for selection only
+    routed_scale: float = 1.0       # routed_scaling_factor on the gates
+    # --- the deployment's share: experts [0, held) live here (0 = all) ----
+    held_experts: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.held_experts or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values are
+    decompressed from a ``kv_lora_rank`` latent, and a
+    ``qk_rope_head_dim``-wide rotary key is shared by every head; the
+    cache holds the latent and the rotary key only."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def cache_width(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +83,7 @@ class ArchConfig:
     sliding_window: int = 0         # window for ATTN_LOCAL layers
     rope_theta: float = 10_000.0
     mrope_sections: Optional[tuple] = None   # qwen2-vl M-RoPE (t, h, w) split
+    mla: Optional[MLAConfig] = None  # latent attention on ATTN layers
     # --- block layout ------------------------------------------------------
     # Pattern of block kinds tiled to num_layers.  E.g. jamba 1:7 ->
     # (ATTN, MAMBA*7); gemma2 -> (ATTN_LOCAL, ATTN); xlstm -> (MLSTM,...,SLSTM)
@@ -60,6 +91,8 @@ class ArchConfig:
     # --- MoE ---------------------------------------------------------------
     moe: Optional[MoEConfig] = None
     moe_every: int = 1          # MoE FFN on layers with i % moe_every == 0
+    # leading ATTN layers with a dense d_ff FFN, before the pattern's stack
+    first_dense_layers: int = 0
     # --- mamba -------------------------------------------------------------
     mamba_d_state: int = 16
     mamba_d_conv: int = 4
@@ -107,9 +140,16 @@ class ArchConfig:
         total = self.vocab_size * d                        # embed
         if not self.tie_embeddings:
             total += self.vocab_size * d
-        for kind in self.blocks:
+        for i, kind in enumerate(self.blocks):
             total += 2 * d                                  # norms
-            if kind in (ATTN, ATTN_LOCAL):
+            if kind in (ATTN, ATTN_LOCAL) and self.mla is not None:
+                a = self.mla
+                total += d * nq * a.qk_head_dim             # q
+                total += d * a.cache_width + a.kv_lora_rank  # kv_a, norm
+                total += a.kv_lora_rank * nq * (a.qk_nope_head_dim
+                                                + a.v_head_dim)
+                total += nq * a.v_head_dim * d              # o
+            elif kind in (ATTN, ATTN_LOCAL):
                 total += d * (nq * h) + 2 * d * (nkv * h) + (nq * h) * d
                 if self.qkv_bias:
                     total += (nq + 2 * nkv) * h
@@ -127,10 +167,12 @@ class ArchConfig:
                 total += 3 * d_in                           # gates
             # FFN
             if self.d_ff > 0 and kind in (ATTN, ATTN_LOCAL, MAMBA):
-                if self.moe is not None:
+                if self.moe is not None and i >= self.first_dense_layers:
                     eff = self.moe.expert_d_ff or self.d_ff
-                    total += self.moe.num_experts * 3 * d * eff
+                    total += self.moe.held * 3 * d * eff
                     total += d * self.moe.num_experts       # router
+                    if self.moe.selection_bias:
+                        total += self.moe.num_experts
                     total += self.moe.num_shared_experts * 3 * d * eff
                 else:
                     total += 3 * d * self.d_ff              # swiglu
@@ -154,8 +196,9 @@ class ArchConfig:
         n_moe_layers = sum(
             1 for i, k in enumerate(self.blocks)
             if k in (ATTN, ATTN_LOCAL, MAMBA) and i % self.moe_every == 0
+            and i >= self.first_dense_layers
         )
-        inactive = (self.moe.num_experts - self.moe.top_k) * dense_expert
+        inactive = (self.moe.held - self.moe.top_k) * dense_expert
         return int(self.param_count() - n_moe_layers * inactive)
 
 

@@ -131,7 +131,8 @@ def apply_rope(x, positions, theta=10_000.0, mrope_sections=None):
 
 def _attend(q, k, v, *, causal, q_offset, window=0, logit_cap=0.0,
             kv_len_mask=None):
-    """q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D).  Chunk-free core."""
+    """q/k: (B, Sq|Sk, Hq|Hkv, D); v: (B, Sk, Hkv, Dv).  Chunk-free
+    core; scores scale by 1/sqrt(D)."""
     b, sq, hq, d = q.shape
     sk, hkv = k.shape[1], k.shape[2]
     group = hq // hkv
@@ -151,7 +152,7 @@ def _attend(q, k, v, *, causal, q_offset, window=0, logit_cap=0.0,
         scores = jnp.where(kv_len_mask[:, None, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, sq, hq, d).astype(q.dtype)
+    return out.reshape(b, sq, hq, v.shape[-1]).astype(q.dtype)
 
 
 def attention(q, k, v, *, causal=True, q_offset=0, window=0, logit_cap=0.0,
@@ -178,7 +179,7 @@ def attention(q, k, v, *, causal=True, q_offset=0, window=0, logit_cap=0.0,
         return carry, out
 
     _, outs = lax.scan(body, None, (jnp.arange(n), qs))
-    return outs.swapaxes(0, 1).reshape(q.shape)
+    return outs.swapaxes(0, 1).reshape(q.shape[:-1] + v.shape[-1:])
 
 
 def attention_specs(cfg, *, cross=False, prefix=""):
@@ -210,7 +211,8 @@ def to_cache_layout(t):
 
 def _write_kv_rows(stacked, layer, rows, slot):
     """Write one (B, Hkv, D) row per batch entry into column ``slot`` of
-    layer ``layer`` of a stacked (G, B, Hkv, D, W) cache, in place.
+    layer ``layer`` of a stacked (G, B, Hkv, D, W) cache, in place (or
+    any (G, B, ..., W) cache: a latent cache's rows are (B, C)).
     ``slot``: scalar, or (B,) when every row has its own position.
 
     Each row goes in through the aligned block of up to 128 positions
@@ -224,7 +226,7 @@ def _write_kv_rows(stacked, layer, rows, slot):
 
     def put(c, i0, r, pos):
         b0 = pos // blk * blk
-        start = (layer, i0, 0, 0, b0)
+        start = (layer, i0) + (0,) * (r.ndim - 1) + (b0,)
         old = lax.dynamic_slice(c, start, (1,) + r.shape + (blk,))
         new = jnp.where(lane == pos - b0, r[None, ..., None], old)
         return lax.dynamic_update_slice(c, new, start)

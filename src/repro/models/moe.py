@@ -13,6 +13,20 @@ Dispatch strategy (TPU-native rethink, see DESIGN.md §4):
 Under ``shard_map`` the dispatch is local to each (pod, data) shard, which is
 how production EP systems route per-device batches.  Without a mesh (CPU smoke
 tests) the same local function runs on the full array with all experts.
+
+Serving (``moe_serve``) drops no token: the assignments to this shard's
+experts are sorted by expert and run through grouped products
+(``lax.ragged_dot``), so the work follows the tokens routed here and a
+long prefill loses nothing to a capacity buffer; a decode step's few
+tokens run every held expert, masked.  Training keeps the capacity path
+above.
+
+The router is the configuration's (``MoEConfig``): softmax over the
+experts, or sigmoid scores with a per-expert selection bias that only
+chooses (DeepSeek-V3's noaux_tc); the chosen gates are renormalised, then
+scaled as configured.  A deployment holds ``held`` of the experts: the
+router keeps its full width, and the layer returns its own experts' part
+plus the shared experts.
 """
 from __future__ import annotations
 
@@ -29,13 +43,16 @@ def moe_specs(cfg):
     m = cfg.moe
     d = cfg.d_model
     f = m.expert_d_ff or cfg.d_ff
+    e = m.held
     specs = {
         "router": ParamSpec((d, m.num_experts), ("embed", None),
                             init="small_normal"),
-        "w_gate": ParamSpec((m.num_experts, d, f), ("expert", "embed", None)),
-        "w_up": ParamSpec((m.num_experts, d, f), ("expert", "embed", None)),
-        "w_down": ParamSpec((m.num_experts, f, d), ("expert", None, "embed")),
+        "w_gate": ParamSpec((e, d, f), ("expert", "embed", None)),
+        "w_up": ParamSpec((e, d, f), ("expert", "embed", None)),
+        "w_down": ParamSpec((e, f, d), ("expert", None, "embed")),
     }
+    if m.selection_bias:
+        specs["bias"] = ParamSpec((m.num_experts,), (None,), init="zeros")
     if m.num_shared_experts:
         fs = f * m.num_shared_experts
         specs["shared"] = {
@@ -44,6 +61,28 @@ def moe_specs(cfg):
             "w_down": ParamSpec((fs, d), ("mlp", "embed")),
         }
     return specs
+
+
+def _route(p, x_flat, moe):
+    """Router over every expert, in float32 -> (logits (N, E), the
+    scores' distribution over the experts (N, E), gates (N, k), chosen
+    experts (N, k))."""
+    f32 = jnp.float32
+    logits = x_flat.astype(f32) @ p["router"].astype(f32)     # (N, E)
+    if moe.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        sel = scores + p["bias"].astype(f32) if moe.selection_bias \
+            else scores
+        _, idx = lax.top_k(sel, moe.top_k)
+        gate = jnp.take_along_axis(scores, idx, axis=-1)
+        probs = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = lax.top_k(probs, moe.top_k)                # (N, k)
+    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    if moe.routed_scale != 1.0:
+        gate = gate * moe.routed_scale
+    return logits, probs, gate, idx
 
 
 def _capacity(n_tokens_local, moe):
@@ -63,10 +102,7 @@ def _moe_local(p, x_flat, *, moe, expert_offset, e_local, capacity,
     k = moe.top_k
     f32 = jnp.float32
 
-    logits = x_flat.astype(f32) @ p["router"].astype(f32)     # (N, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = lax.top_k(probs, k)                           # (N, k)
-    gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    logits, probs, gate, idx = _route(p, x_flat, moe)
 
     # ---- aux losses (computed on replicated routing; identical per shard)
     me = jnp.mean(probs, axis=0)                              # (E,)
@@ -109,15 +145,82 @@ def _moe_local(p, x_flat, *, moe, expert_offset, e_local, capacity,
         jnp.where(dropped[:, None], jnp.zeros((), dt), contrib))
 
     # ---- shared experts (dense, model-sharded d_ff -> partial sums)
-    if "shared" in p:
-        sp = p["shared"]
-        sg = jax.nn.silu(x_flat @ sp["w_gate"].astype(dt))
-        su = x_flat @ sp["w_up"].astype(dt)
-        y = y + (sg * su) @ sp["w_down"].astype(dt)
-
+    y = _add_shared(p, x_flat, y)
     if psum_axis is not None:
         y = lax.psum(y, psum_axis)
     return y, aux
+
+
+def _add_shared(p, x_flat, y):
+    """``y`` plus the shared experts' output (one SwiGLU of their summed
+    width), when the layer has them."""
+    if "shared" not in p:
+        return y
+    dt = x_flat.dtype
+    sp = p["shared"]
+    sg = jax.nn.silu(x_flat @ sp["w_gate"].astype(dt))
+    su = x_flat @ sp["w_up"].astype(dt)
+    return y + (sg * su) @ sp["w_down"].astype(dt)
+
+
+#: Up to this many tokens (a decode step's slots), every held expert
+#: runs on every token, masked to the ones that chose it: that reads each
+#: held expert's weights once, as the grouped products do, while a
+#: grouped product pads each held expert's rows to a tile of 512 (41 us a
+#: (layer, expert) pair that got a token on a v5e, PERF.md).
+DENSE_TOKENS = 128
+
+
+def _moe_dropless(p, x_flat, *, moe, expert_offset, e_local,
+                  psum_axis=None):
+    """Local-shard MoE for serving, dropping no token: x_flat (N, d).
+
+    Up to ``DENSE_TOKENS`` tokens, each held expert's SwiGLU runs on
+    every token and is weighted by the token's gate for it (0 unless
+    chosen).  Past that, the N*k assignments are sorted by expert, those
+    of experts outside [expert_offset, expert_offset + e_local) last;
+    the held experts' SwiGLU runs as three grouped products over their
+    rows only, and each row is added back to its token with its gate.
+    Returns (partial y (N, d), load (N, e_local) int32: 1 where a token
+    was routed to a held expert)."""
+    n, d = x_flat.shape
+    k = moe.top_k
+    dt = x_flat.dtype
+    f32 = jnp.float32
+    _, _, gate, idx = _route(p, x_flat, moe)
+    local = idx.reshape(-1) - expert_offset                   # (N*k,)
+    mine = (local >= 0) & (local < e_local)
+    key = jnp.where(mine, local, e_local)
+    load = jnp.sum(jax.nn.one_hot(key.reshape(n, k), e_local,
+                                  dtype=jnp.int32), axis=1)
+    wg, wu, wd = (p[w].astype(dt) for w in ("w_gate", "w_up", "w_down"))
+    if n <= DENSE_TOKENS:
+        w = jnp.sum(jax.nn.one_hot(key.reshape(n, k), e_local, dtype=f32)
+                    * gate[..., None], axis=1)                # (N, e_local)
+        h = jax.nn.silu(jnp.einsum("nd,edf->nef", x_flat, wg)) \
+            * jnp.einsum("nd,edf->nef", x_flat, wu)
+        y = jnp.einsum("nef,efd->ned", h, wd)
+        y = jnp.einsum("ned,ne->nd", y.astype(f32), w).astype(dt)
+    else:
+        order = jnp.argsort(key, stable=True)
+        tok = (order // k).astype(jnp.int32)
+        sizes = jnp.bincount(key, length=e_local + 1)[:e_local] \
+            .astype(jnp.int32)
+        xs = x_flat[tok]
+        h = jax.nn.silu(lax.ragged_dot(xs, wg, sizes)) \
+            * lax.ragged_dot(xs, wu, sizes)
+        out = lax.ragged_dot(h, wd, sizes)                    # (N*k, d)
+        held = mine[order]
+        w = gate.reshape(-1)[order]
+        # rows past the held groups are not computed: select, never
+        # multiply
+        contrib = jnp.where(held[:, None], out.astype(f32) * w[:, None],
+                            0.0)
+        y = jnp.zeros((n, d), f32).at[tok].add(contrib).astype(dt)
+    y = _add_shared(p, x_flat, y)
+    if psum_axis is not None:
+        y = lax.psum(y, psum_axis)
+    return y, load
 
 
 def moe_apply(p, cfg, x, *, mesh=None, ep_axis="model",
@@ -129,22 +232,12 @@ def moe_apply(p, cfg, x, *, mesh=None, ep_axis="model",
     if mesh is None or ep_axis not in mesh.axis_names:
         xf = x.reshape(b * s, d)
         y, aux = _moe_local(p, xf, moe=moe, expert_offset=0,
-                            e_local=moe.num_experts,
+                            e_local=moe.held,
                             capacity=_capacity(b * s, moe))
         return y.reshape(b, s, d), aux
 
-    ep = mesh.shape[ep_axis]
-    assert moe.num_experts % ep == 0, \
-        f"{moe.num_experts} experts not divisible by EP={ep}"
-    e_local = moe.num_experts // ep
-    dp_axes = tuple(a for a in dp_axes if a in mesh.axis_names)
-    dp = 1
-    for a in dp_axes:
-        dp *= mesh.shape[a]
-    if b % dp != 0:                 # tiny batches (long_500k) replicate
-        dp_axes, dp = (), 1
-    n_local = (b // dp) * s
-    capacity = _capacity(n_local, moe)
+    e_local, dp_axes, dp = _ep_layout(moe, mesh, ep_axis, dp_axes, b)
+    capacity = _capacity((b // dp) * s, moe)
 
     def shard_fn(p_loc, x_loc):
         off = lax.axis_index(ep_axis) * e_local
@@ -154,6 +247,31 @@ def moe_apply(p, cfg, x, *, mesh=None, ep_axis="model",
                             psum_axis=ep_axis)
         return y.reshape(x_loc.shape), aux
 
+    y, aux = _ep_shard_map(shard_fn, p, x, mesh, ep_axis, dp_axes, P())
+    return y, aux
+
+
+def _ep_layout(moe, mesh, ep_axis, dp_axes, b):
+    """The expert-parallel layout -> (held experts a shard, the mesh's
+    batch axes, their size): the held experts split over ``ep_axis``, a
+    batch of ``b`` over ``dp_axes``, or replicated when it does not
+    divide."""
+    ep = mesh.shape[ep_axis]
+    assert moe.held % ep == 0, \
+        f"{moe.held} experts not divisible by EP={ep}"
+    dp_axes = tuple(a for a in dp_axes if a in mesh.axis_names)
+    dp = 1
+    for a in dp_axes:
+        dp *= mesh.shape[a]
+    if b % dp != 0:                 # tiny batches (long_500k) replicate
+        dp_axes, dp = (), 1
+    return moe.held // ep, dp_axes, dp
+
+
+def _ep_shard_map(shard_fn, p, x, mesh, ep_axis, dp_axes, extra_spec):
+    """Run ``shard_fn(p_local, x_local) -> (y, extra)`` with the experts
+    and the shared experts' width split over ``ep_axis`` and the batch
+    over ``dp_axes``."""
     # cast expert weights to compute dtype BEFORE shard_map so the FSDP
     # all-gather into the region moves bf16, not fp32 (halves gather temp)
     p = jax.tree.map(lambda w: w.astype(x.dtype), p)
@@ -166,9 +284,33 @@ def moe_apply(p, cfg, x, *, mesh=None, ep_axis="model",
                              "w_down": P(ep_axis, None)}
     x_spec = P(dp_axes if dp_axes else None, None, None)
     from repro.distributed.sharding import shard_map_compat
-    y, aux = shard_map_compat(
+    return shard_map_compat(
         shard_fn, mesh=mesh,
         in_specs=(p_specs, x_spec),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, extra_spec),
     )(p, x)
-    return y, aux
+
+
+def moe_serve(p, cfg, x, *, mesh=None, ep_axis="model",
+              dp_axes=("pod", "data")):
+    """The serving MoE layer, dropping no token: x (B, S, d) -> (y,
+    load (B*S, held) int32, each token's assignments to the held
+    experts)."""
+    moe = cfg.moe
+    b, s, d = x.shape
+    with jax.named_scope("moe"):
+        if mesh is None or ep_axis not in mesh.axis_names:
+            y, load = _moe_dropless(p, x.reshape(b * s, d), moe=moe,
+                                    expert_offset=0, e_local=moe.held)
+            return y.reshape(b, s, d), load
+        e_local, dp_axes, _ = _ep_layout(moe, mesh, ep_axis, dp_axes, b)
+
+        def shard_fn(p_loc, x_loc):
+            off = lax.axis_index(ep_axis) * e_local
+            y, load = _moe_dropless(p_loc, x_loc.reshape(-1, d), moe=moe,
+                                    expert_offset=off, e_local=e_local,
+                                    psum_axis=ep_axis)
+            return y.reshape(x_loc.shape), load
+
+        return _ep_shard_map(shard_fn, p, x, mesh, ep_axis, dp_axes,
+                             P(dp_axes if dp_axes else None, ep_axis))

@@ -21,16 +21,21 @@ from repro.configs.base import (ArchConfig, ATTN, ATTN_LOCAL, MAMBA, MLSTM,
                                 SLSTM)
 from repro.models import layers as L
 from repro.models import mamba as M
+from repro.models import mla as MLA
 from repro.models import xlstm as X
 from repro.models import moe as MOE
 from repro.models.layers import ParamSpec
 
 
 def _block_specs(cfg: ArchConfig, kind: str, layer_pos: int, *,
-                 cross: bool = False):
+                 cross: bool = False, dense: bool = False):
+    """One block's ParamSpecs; ``dense``: a leading layer, whose FFN is a
+    SwiGLU of width d_ff whatever the MoE layers hold."""
     d = cfg.d_model
     specs = {"norm1": ParamSpec((d,), ("embed",), init="zeros")}
-    if kind in (ATTN, ATTN_LOCAL):
+    if kind in (ATTN, ATTN_LOCAL) and cfg.mla is not None:
+        specs["core"] = MLA.mla_specs(cfg)
+    elif kind in (ATTN, ATTN_LOCAL):
         specs["core"] = L.attention_specs(cfg)
     elif kind == MAMBA:
         specs["core"] = M.mamba_specs(cfg)
@@ -45,7 +50,7 @@ def _block_specs(cfg: ArchConfig, kind: str, layer_pos: int, *,
         specs["cross"] = L.attention_specs(cfg, cross=True)
     if _has_ffn(cfg, kind):
         specs["norm2"] = ParamSpec((d,), ("embed",), init="zeros")
-        if _is_moe_layer(cfg, layer_pos):
+        if _is_moe_layer(cfg, layer_pos) and not dense:
             specs["ffn"] = MOE.moe_specs(cfg)
         else:
             specs["ffn"] = L.mlp_specs(cfg)
@@ -84,9 +89,12 @@ class Model:
     def __init__(self, cfg: ArchConfig):
         self.cfg = cfg
         self.pattern = tuple(cfg.block_pattern)
-        assert cfg.num_layers % len(self.pattern) == 0, \
-            f"{cfg.num_layers} layers not divisible by pattern {self.pattern}"
-        self.n_groups = cfg.num_layers // len(self.pattern)
+        # leading dense layers run first, as a stack of their own
+        self.n_lead = cfg.first_dense_layers
+        n_body = cfg.num_layers - self.n_lead
+        assert n_body % len(self.pattern) == 0, \
+            f"{n_body} layers not divisible by pattern {self.pattern}"
+        self.n_groups = n_body // len(self.pattern)
         if cfg.moe is not None:
             assert len(self.pattern) % cfg.moe_every == 0 or cfg.moe_every == 1
         self.compute_dtype = jnp.dtype(cfg.compute_dtype)
@@ -109,6 +117,9 @@ class Model:
         for p_idx, kind in enumerate(self.pattern):
             specs["layers"][f"pos{p_idx}"] = _stack_specs(
                 _block_specs(cfg, kind, p_idx, cross=cross), self.n_groups)
+        if self.n_lead:
+            specs["lead"] = {"pos0": _stack_specs(
+                _block_specs(cfg, ATTN, 0, dense=True), self.n_lead)}
         if cfg.encoder_layers:
             specs["encoder"] = {
                 "pos_embed": ParamSpec((cfg.num_audio_frames, d),
@@ -131,18 +142,28 @@ class Model:
     # ------------------------------------------------------------------
     # Block application
     # ------------------------------------------------------------------
-    def _apply_block(self, kind, p, x, positions, *, layer_pos, layer=None,
+    def _apply_block(self, kind, p, x, positions, *, layer=None,
                      cache=None, cache_index=None, enc_out=None,
                      causal=True):
-        """One block.  ``cache``: this pattern position's stacked cache
-        (every layer's entries on a leading axis) or None; the block
-        reads and writes only layer ``layer``'s entries and returns the
-        whole stacked cache, so the buffer is updated in place."""
+        """One block -> (x, cache, aux loss, load).  ``cache``: this
+        pattern position's stacked cache (every layer's entries on a
+        leading axis) or None; the block reads and writes only layer
+        ``layer``'s entries and returns the whole stacked cache, so the
+        buffer is updated in place.  With a cache (serving) an MoE FFN
+        drops no token and ``load`` is its (tokens, held experts)
+        assignments; else None."""
         cfg = self.cfg
         aux = jnp.zeros((), jnp.float32)
+        load = None
         new_cache = dict(cache) if cache is not None else {}
         h = L.rms_norm(x, p["norm1"], cfg.rms_eps)
-        if kind in (ATTN, ATTN_LOCAL):
+        if kind in (ATTN, ATTN_LOCAL) and cfg.mla is not None:
+            out, lat = MLA.mla_apply(
+                p["core"], cfg, h, positions, cache=new_cache.get("latent"),
+                layer=layer, cache_index=cache_index)
+            if lat is not None:
+                new_cache["latent"] = lat
+        elif kind in (ATTN, ATTN_LOCAL):
             window = cfg.sliding_window if kind == ATTN_LOCAL else 0
             out, nkv = L.attention_apply(
                 p["core"], cfg, h, positions, layer_window=window,
@@ -193,13 +214,15 @@ class Model:
 
         if "ffn" in p:
             hf = L.rms_norm(x, p["norm2"], cfg.rms_eps)
-            if _is_moe_layer(cfg, layer_pos):
+            if "router" in p["ffn"] and cache is not None:
+                out, load = MOE.moe_serve(p["ffn"], cfg, hf, mesh=self.mesh)
+            elif "router" in p["ffn"]:
                 out, a = MOE.moe_apply(p["ffn"], cfg, hf, mesh=self.mesh)
                 aux = aux + a
             else:
                 out = L.mlp_apply(p["ffn"], hf)
             x = x + out
-        return x, new_cache, aux
+        return x, new_cache, aux, load
 
     mesh = None   # set by the distribution layer (None => local smoke mode)
 
@@ -226,9 +249,15 @@ class Model:
     # Stack runner
     # ------------------------------------------------------------------
     def _run_stack(self, stacked_params, x, positions, *, caches=None,
-                   cache_index=None, enc_out=None, remat=None):
+                   cache_index=None, enc_out=None, remat=None,
+                   pattern=None):
+        """Scan the stacked groups of ``pattern`` (the model's own by
+        default) -> (x, aux loss, caches, load): load stacks the MoE
+        blocks' serving loads, (MoE layers, tokens, held experts), or is
+        None."""
         cfg = self.cfg
-        pattern = self.pattern
+        pattern = self.pattern if pattern is None else pattern
+        n_groups = jax.tree.leaves(stacked_params)[0].shape[0]
         remat = cfg.remat if remat is None else remat
         import os
         if os.environ.get("REPRO_GATHER_BF16") == "1":
@@ -244,17 +273,20 @@ class Model:
             # new entries into the stacked buffers in place
             xc, aux_sum, cc = carry
             pg, layer = scan_in
+            loads = []
             for p_idx, kind in enumerate(pattern):
                 key = f"pos{p_idx}"
-                xc, nc, aux = self._apply_block(
-                    kind, pg[key], xc, positions, layer_pos=p_idx,
+                xc, nc, aux, load = self._apply_block(
+                    kind, pg[key], xc, positions,
                     layer=layer, cache=cc[key] if cc is not None else None,
                     cache_index=cache_index, enc_out=enc_out)
                 xc = self._constrain_act(xc)
                 if cc is not None:
                     cc = {**cc, key: nc}
                 aux_sum = aux_sum + aux
-            return (xc, aux_sum, cc), None
+                if load is not None:
+                    loads.append(load)
+            return (xc, aux_sum, cc), (jnp.stack(loads) if loads else None)
 
         if remat:
             import os
@@ -263,10 +295,32 @@ class Model:
                       if pol == "dots"
                       else jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(body, policy=policy)
-        (x, aux, caches), _ = lax.scan(
+        (x, aux, caches), load = lax.scan(
             body, (x, jnp.zeros((), jnp.float32), caches),
-            (stacked_params, jnp.arange(self.n_groups)))
-        return x, aux, caches
+            (stacked_params, jnp.arange(n_groups)))
+        if load is not None:
+            load = load.reshape((-1,) + load.shape[2:])
+        return x, aux, caches, load
+
+    def _run_layers(self, params, x, positions, *, caches=None,
+                    cache_index=None, enc_out=None, remat=None):
+        """The leading dense layers (their cache under ``"lead"``), then
+        the pattern's stack -> (x, aux loss, caches, load)."""
+        kw = dict(cache_index=cache_index, enc_out=enc_out, remat=remat)
+        if not self.n_lead:
+            return self._run_stack(params["layers"], x, positions,
+                                   caches=caches, **kw)
+        lead = None
+        if caches is not None:
+            caches = dict(caches)
+            lead = caches.pop("lead")
+        x, aux0, lead, _ = self._run_stack(
+            params["lead"], x, positions, caches=lead, pattern=(ATTN,), **kw)
+        x, aux, caches, load = self._run_stack(
+            params["layers"], x, positions, caches=caches, **kw)
+        if caches is not None:
+            caches["lead"] = lead
+        return x, aux0 + aux, caches, load
 
     # ------------------------------------------------------------------
     # Embedding / unembedding
@@ -343,8 +397,8 @@ class Model:
 
         def body(carry, pg):
             xc, _ = carry
-            xc, _, _ = self._apply_block(ATTN, pg["pos0"], xc, pos,
-                                         layer_pos=0, causal=False)
+            xc, _, _, _ = self._apply_block(ATTN, pg["pos0"], xc, pos,
+                                            causal=False)
             return (xc, jnp.zeros((), jnp.float32)), None
 
         (x, _), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
@@ -359,8 +413,8 @@ class Model:
         x = self._embed(params, batch)
         positions = self._positions(batch, x.shape[1])
         enc_out = self._encode(params, batch) if cfg.encoder_layers else None
-        x, aux, _ = self._run_stack(params["layers"], x, positions,
-                                    enc_out=enc_out)
+        x, aux, _, _ = self._run_layers(params, x, positions,
+                                        enc_out=enc_out)
         x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
         labels = batch.get("labels", batch["tokens"])
         ce = self._logits(params, x, chunked_labels=labels)
@@ -370,16 +424,27 @@ class Model:
     def cache_specs(self, batch_size, max_len):
         """ShapeDtypeStruct pytree for the decode cache."""
         import os
-        cfg = self.cfg
-        h, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
         cd = self.compute_dtype
         kv_dt = os.environ.get("REPRO_KV_DTYPE")   # §Perf knob (e.g. f8)
         cd = jnp.dtype(kv_dt) if kv_dt else cd
-        g = self.n_groups
+        caches = self._stack_cache_specs(self.pattern, self.n_groups,
+                                         batch_size, max_len, cd)
+        if self.n_lead:
+            caches["lead"] = self._stack_cache_specs(
+                (ATTN,), self.n_lead, batch_size, max_len, cd)
+        return caches
+
+    def _stack_cache_specs(self, pattern, g, batch_size, max_len, cd):
+        cfg = self.cfg
+        h, nkv = cfg.resolved_head_dim, cfg.num_kv_heads
         caches = {}
-        for p_idx, kind in enumerate(self.pattern):
+        for p_idx, kind in enumerate(pattern):
             c = {}
-            if kind in (ATTN, ATTN_LOCAL):
+            if kind in (ATTN, ATTN_LOCAL) and cfg.mla is not None:
+                # [c, k_r] of every position, sequence on the lanes
+                c["latent"] = jax.ShapeDtypeStruct(
+                    (g, batch_size, cfg.mla.cache_width, max_len), cd)
+            elif kind in (ATTN, ATTN_LOCAL):
                 # Sliding-window layers use a ring cache bounded by the
                 # window (position p -> slot p % W).
                 eff = max_len
@@ -424,8 +489,8 @@ class Model:
         s = x.shape[1]
         positions = self._positions(batch, s)
         enc_out = self._encode(params, batch) if cfg.encoder_layers else None
-        x, _, cache = self._run_stack(
-            params["layers"], x, positions, caches=cache,
+        x, _, cache, _ = self._run_layers(
+            params, x, positions, caches=cache,
             cache_index=jnp.zeros((), jnp.int32), enc_out=enc_out)
         x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
         logits = self._logits(params, x[:, -1:])
@@ -433,13 +498,20 @@ class Model:
 
     def decode_step(self, params, batch, cache, pos):
         """batch["tokens"]: (B, 1); pos: scalar int32 current length."""
+        logits, cache, _ = self.decode_step_load(params, batch, cache, pos)
+        return logits, cache
+
+    def decode_step_load(self, params, batch, cache, pos):
+        """``decode_step`` -> (logits, cache, load): load is each MoE
+        layer's assignments of each row's token to the held experts,
+        (MoE layers, B, held) int32, or None for a model without MoE."""
         cfg = self.cfg
         x = self._embed(params, batch)
         positions = self._positions(batch, 1, offset=pos)
-        enc_out = None   # cross kv comes from the cache during decode
-        x, _, cache = self._run_stack(params["layers"], x, positions,
-                                      caches=cache, cache_index=pos,
-                                      enc_out=enc_out, remat=False)
+        # cross kv comes from the cache during decode
+        x, _, cache, load = self._run_layers(params, x, positions,
+                                             caches=cache, cache_index=pos,
+                                             remat=False)
         x = L.rms_norm(x, params["final_norm"], cfg.rms_eps)
         logits = self._logits(params, x)
-        return logits, cache
+        return logits, cache, load

@@ -79,18 +79,28 @@ def _make_masked_step(model: Model):
     inactive slots pinned to token 0 at position 0 (their cache rows
     are rewritten at the next admission, so the garbage write is never
     read), and the new token scattered into column ``w`` of the
-    device-side token buffer."""
+    device-side token buffer.
 
-    def step(params, cache, tok, pos, active, buf, w):
+    A model with MoE layers also takes and returns ``load``, int32
+    (2,): the held-expert load counter, to which each step adds the
+    active slots' token-to-held-expert assignments and the (layer, held
+    expert) pairs that got at least one of them."""
+
+    def step(params, cache, tok, pos, active, buf, w, *load):
         cur = jnp.where(active, pos + w, 0).astype(jnp.int32)
         tok_c = jnp.where(active, tok, 0).astype(jnp.int32)
-        logits, cache = model.decode_step(
+        logits, cache, lay = model.decode_step_load(
             params, {"tokens": tok_c[:, None], "positions": cur[:, None]},
             cache, cur)
         nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
         nxt = jnp.where(active, nxt, 0)
         buf = buf.at[:, w].set(nxt)
-        return nxt, cache, buf
+        if not load:
+            return nxt, cache, buf
+        lay = jnp.where(active[None, :, None], lay, 0)   # (layers, B, held)
+        hit = jnp.sum(jnp.any(lay > 0, axis=1), dtype=jnp.int32)
+        return nxt, cache, buf, load[0] + jnp.stack(
+            [jnp.sum(lay, dtype=jnp.int32), hit])
 
     return _jit("serve_decode_step", step, donate_argnums=(1, 5))
 
@@ -261,6 +271,11 @@ class ServeEngine(_AttributionMixin):
         self._pend = jnp.zeros((self.slots,), jnp.int32)
         self._buf = jnp.zeros((self.slots, self.flush_interval),
                               jnp.int32)
+        # held-expert load: assignments and (layer, expert) pairs hit,
+        # summed over the decode steps of the engine's life
+        self.routed = model.cfg.moe is not None
+        self.route_assignments = 0
+        self.route_pairs = 0
         # gauges / counters (exported via HealthRegistry.track_serve)
         self.host_transfers = 0
         self.requests_served = 0
@@ -332,14 +347,26 @@ class ServeEngine(_AttributionMixin):
         act = jnp.asarray(active)
         posd = jnp.asarray(pos, jnp.int32)
         tok, buf = self._nxt, self._buf
+        load = (jnp.zeros((2,), jnp.int32),) if self.routed else ()
         for t in range(k):
-            tok, self.cache, buf = self._step(
+            tok, self.cache, buf, *load = self._step(
                 self.params, self.cache, tok, posd, act, buf,
-                jnp.asarray(t, jnp.int32))
+                jnp.asarray(t, jnp.int32), *load)
         self._nxt, self._buf = tok, buf
         with tracing.span("serve.drain"):
-            toks = self._to_host(
-                jnp.concatenate([self._pend[:, None], buf], axis=1))
+            toks = jnp.concatenate([self._pend[:, None], buf], axis=1)
+            if load:
+                # the load counter rides on the token drain: one transfer
+                self.host_transfers += 1
+                toks, load = jax.device_get((toks, load[0]))
+            else:
+                toks = self._to_host(toks)
+        if len(load):
+            assign, pairs = (int(v) for v in load)
+            with tracing.span("serve.route", n=k, assignments=assign,
+                              pairs=pairs):
+                self.route_assignments += assign
+                self.route_pairs += pairs
         t1 = self.tracer.now()
         if k:
             self.tracer.add_region("decode", t0, t1, depth=0)

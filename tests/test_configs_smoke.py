@@ -87,10 +87,9 @@ def test_param_counts_sane():
         "minicpm-2b": 2.7e9, "qwen2-vl-2b": 1.5e9,
         "qwen3-moe-235b-a22b": 235e9, "jamba-1.5-large-398b": 398e9,
         "whisper-base": 74e6, "xlstm-1.3b": 1.3e9,
-        # the assigned pool config (48L x 64e x d_ff 1408 + 2 shared)
-        # arithmetically gives ~28.5B, not the checkpoint's 16B —
-        # we implement the assignment as specified
-        "moonshot-v1-16b-a3b": 28.5e9,
+        # published config.json: one dense layer, 26 MoE layers of 64
+        # experts + 2 shared, MLA, untied vocabulary -> 15.96B
+        "moonlight-16b-a3b": 16.0e9,
     }
     for name, target in expected.items():
         model = Model(get_arch(name))

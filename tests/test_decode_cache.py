@@ -4,8 +4,8 @@ The caches ride in the layer loop's carry, so a step writes each slot's
 new K/V row into the donated stacked buffer and reads each layer once;
 no cache-sized copy, fill or per-layer write-back appears in the
 compiled step.  Parity: for every cache kind the carry threads (full,
-ring, cross, mamba state), stepwise masked decode at per-slot positions
-gives the logits of a prefill over the same tokens.
+ring, cross, mamba state, latent), stepwise masked decode at per-slot
+positions gives the logits of a prefill over the same tokens.
 """
 import dataclasses
 import re
@@ -103,10 +103,11 @@ def test_decode_step_writes_cache_in_place():
 
 
 def _decode_vs_prefill(arch, slot_lens, steps=4, max_len=32):
-    # float32, so that the two paths agree to rounding; no MoE, whose
-    # expert capacity drops tokens of a prefill but none of a 1-token step
+    # float32, so that the two paths agree to rounding; MoE layers
+    # included: serving routes without capacity, so a prefill drops no
+    # token that a one-token step would keep
     cfg = dataclasses.replace(reduced(get_arch(arch)),
-                              compute_dtype="float32", moe=None)
+                              compute_dtype="float32")
     model = Model(cfg)
     params = model.init(jax.random.key(3))
     rng = np.random.default_rng(7)
@@ -153,6 +154,7 @@ def _decode_vs_prefill(arch, slot_lens, steps=4, max_len=32):
     pytest.param("gemma2-27b", id="ring"),    # local layers: 8 positions
     pytest.param("whisper-base", id="cross"),
     pytest.param("jamba-1.5-large-398b", id="mamba"),
+    pytest.param("moonlight-16b-a3b", id="latent"),
 ])
 def test_masked_decode_per_slot_matches_prefill(arch):
     # per-slot positions; gemma2's longest slot wraps its ring

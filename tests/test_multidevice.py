@@ -123,6 +123,38 @@ def test_moe_shard_map_matches_local():
     """)
 
 
+def test_moe_serve_shard_map_matches_local():
+    """The serving MoE over an expert-parallel mesh (4 shards of 8
+    experts, the batch over 2): each shard's grouped products over its
+    own experts, summed, equal the one-device layer, and the loads
+    concatenate to the one-device loads."""
+    run_py("""
+        import dataclasses
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import Mesh
+        from repro.configs import get_arch, reduced
+        from repro.models.moe import moe_serve, moe_specs
+        from repro.models.layers import init_params
+        base = reduced(get_arch("moonlight-16b-a3b"))
+        cfg = dataclasses.replace(base, moe=dataclasses.replace(
+            base.moe, num_experts=8, top_k=3))
+        p = init_params(moe_specs(cfg), jax.random.key(0))
+        x = jax.random.normal(jax.random.key(1), (4, 8, cfg.d_model),
+                              jnp.float32)
+        y_local, load_local = moe_serve(p, cfg, x, mesh=None)
+        mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4),
+                    ("data", "model"))
+        with mesh:
+            y_sh, load_sh = jax.jit(
+                lambda p, x: moe_serve(p, cfg, x, mesh=mesh))(p, x)
+        np.testing.assert_allclose(np.asarray(y_sh), np.asarray(y_local),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(np.asarray(load_sh),
+                                      np.asarray(load_local))
+        print("moe serve shard_map OK")
+    """)
+
+
 def test_train_step_sharded_matches_single_device():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np

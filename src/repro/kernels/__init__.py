@@ -12,6 +12,8 @@ interpret=True mode on CPU; on TPU the same BlockSpecs drive MXU/VMEM.
   xcorr_align       — lag-bank normalized cross-correlation (delay est.)
   flash_attention   — causal GQA flash attention (+gemma2 softcap)
   ssm_scan          — selective-scan (mamba) inner recurrence
+  latent_decode     — one decode query per slot over its own filled
+                      blocks of a stacked latent (MLA) cache
 """
 
 

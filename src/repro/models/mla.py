@@ -18,10 +18,13 @@ W_UV,h``, so keys and values are never decompressed for the cache.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.kernels.latent_decode import latent_decode
 from repro.models.layers import (ParamSpec, _write_kv_rows, apply_rope,
                                  attention, rms_norm)
 
@@ -78,38 +81,24 @@ def mla_apply(p, cfg, x, positions, *, cache=None, layer=None,
             cache = _write_kv_rows(cache, layer,
                                    rows[:, 0].astype(cache.dtype),
                                    cache_index)
-            # the scores read all r + R rows, the output the r latent
-            # rows: two slices of the layer, each read in place by its
-            # product (one shared slice would be copied out, whole)
-            out = _absorbed_decode(
-                q_n[:, 0], q_r[:, 0], wkv_b, _layer_rows(cache, layer),
-                _layer_rows(cache, layer, r), cache_index, nope)[:, None]
+            out = _absorbed_decode(q_n[:, 0], q_r[:, 0], wkv_b, cache,
+                                   layer, cache_index)[:, None]
         out = out.astype(dt).reshape(b, s, h * dv)
         return out @ p["wo"].astype(dt), cache
 
 
-def _layer_rows(cache, layer, rows=None):
-    """Layer ``layer``'s (B, rows, S) of a stacked (G, B, C, S) cache
-    (every row by default)."""
-    g, b, c, s = cache.shape
-    return lax.dynamic_slice(cache, (layer, 0, 0, 0),
-                             (1, b, rows or c, s))[0]
-
-
-def _absorbed_decode(q_n, q_r, wkv_b, lat, lat_c, pos, nope):
-    """One query per row over a (B, r + R, S) latent cache ``lat``
-    (``lat_c``: its first r rows), absorbed: q_n (B, H, nope), q_r
-    (B, H, R), wkv_b (r, H, nope + dv); positions <= ``pos`` (scalar or
-    (B,)) are valid.  -> (B, H, dv) float32."""
+def _absorbed_decode(q_n, q_r, wkv_b, cache, layer, pos):
+    """One query per row over layer ``layer`` of the stacked (G, B,
+    r + R, S) latent cache, absorbed: q_n (B, H, nope), q_r (B, H, R),
+    wkv_b (r, H, nope + dv); positions <= ``pos`` (scalar or (B,)) are
+    valid.  -> (B, H, dv) float32.  The cache is handed whole to
+    ``latent_decode``, which reads only the layer's filled positions."""
     f32 = jnp.float32
+    nope, r = q_n.shape[-1], wkv_b.shape[0]
     q_lat = jnp.einsum("bhn,chn->bhc", q_n.astype(f32),
                        wkv_b[..., :nope].astype(f32))         # (B, H, r)
     qc = jnp.concatenate([q_lat, q_r.astype(f32)], axis=-1)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(nope + q_r.shape[-1], f32))
-    scores = jnp.einsum("bhc,bcs->bhs", qc, lat.astype(f32)) * scale
-    slots = jnp.arange(lat.shape[-1])
-    valid = slots[None, :] <= jnp.reshape(pos, (-1, 1))       # (B|1, S)
-    scores = jnp.where(valid[:, None, :], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o_lat = jnp.einsum("bhs,bcs->bhc", probs, lat_c.astype(f32))
+    pos = jnp.broadcast_to(pos, q_n.shape[:1]).astype(jnp.int32)
+    o_lat = latent_decode(qc, cache, layer, pos, rank=r,
+                          scale=1.0 / math.sqrt(nope + q_r.shape[-1]))
     return jnp.einsum("bhc,chv->bhv", o_lat, wkv_b[..., nope:].astype(f32))

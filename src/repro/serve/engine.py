@@ -37,6 +37,7 @@ import numpy as np
 from repro.core import tracing
 from repro.core.tracing import RegionTracer
 from repro.fleet.pipeline import SlotSegment
+from repro.kernels.latent_decode import block_positions
 from repro.models import Model
 from repro.serve.metering import (RequestEnergy, RequestEnergyReport,
                                   RollingPercentiles)
@@ -276,6 +277,10 @@ class ServeEngine(_AttributionMixin):
         self.routed = model.cfg.moe is not None
         self.route_assignments = 0
         self.route_pairs = 0
+        # latent attention: positions in each block of the cache that a
+        # decode step reads up to every slot's position (0: no latent cache)
+        self.latent_block = (block_positions(self.max_len)
+                             if model.cfg.mla is not None else 0)
         # gauges / counters (exported via HealthRegistry.track_serve)
         self.host_transfers = 0
         self.requests_served = 0
@@ -337,6 +342,19 @@ class ServeEngine(_AttributionMixin):
         r.t_admitted = t0
         r.t_first = t2
         return lb
+
+    def _latent_stats(self, pos, active, k) -> dict:
+        """The ``serve.decode`` span's stats for a latent-attention model:
+        the latent cache blocks each layer reads over the segment's ``k``
+        steps for the active slots (``latent_blocks``; slot b at position
+        p reads blocks 0 .. p // block), and those the slots hold
+        (``latent_blocks_held``), from the host's positions."""
+        if not self.latent_block:
+            return {}
+        at = pos[active][:, None] + np.arange(k)
+        return {"latent_blocks": int(np.sum(at // self.latent_block + 1)),
+                "latent_blocks_held":
+                    self.slots * (self.max_len // self.latent_block) * k}
 
     def _decode_segment(self, k, slot_req, pos, remaining, active,
                         pend_fresh, results):
@@ -458,8 +476,8 @@ class ServeEngine(_AttributionMixin):
             if not active.any():
                 continue
             k = int(min(self.flush_interval, remaining[active].min()))
-            with tracing.span("serve.decode", n=k,
-                              active=self.active_slots):
+            with tracing.span("serve.decode", n=k, active=self.active_slots,
+                              **self._latent_stats(pos, active, k)):
                 self._decode_segment(k, slot_req, pos, remaining, active,
                                      pend_fresh, results)
             self.active_slots = int(active.sum())

@@ -1,10 +1,12 @@
-"""Compile rehearsals of the fleet kernels for a TPU v5e, with no chip.
+"""Compile rehearsals of the fleet kernels and the latent decode kernel
+for a TPU v5e, with no chip.
 
 Interpret mode runs every kernel on the CPU and cannot see what the
 chip's compiler refuses (boolean stores, unsupported gathers, block
 shapes).  Each test here lowers one kernel at fleet width (2,560 rows x
-4,096 samples, float32) for a described v5e chip and checks that the
-compiled program holds the Mosaic kernel (``tpu_custom_call``).
+4,096 samples, float32), or at moonlight-16b-a3b's serving shapes, for
+a described v5e chip and checks that the compiled program holds the
+Mosaic kernel (``tpu_custom_call``).
 
 The topology is described only inside the module fixture: one process
 at a time may load the TPU library, so nothing here may touch it while
@@ -103,3 +105,25 @@ def test_fleet_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = CASES[name]
     text = _compiled_text(fn, one_chip, *shapes)
     assert "tpu_custom_call" in text, name
+
+
+def test_latent_decode_compiles_for_v5e(one_chip):
+    """moonlight-16b-a3b's decode read: 16 slots, 16 heads, the stacked
+    (26, 16, 576, 8192) bf16 cache handed whole to the kernel, which
+    copies only its blocks: no temp buffer, and the custom call's
+    operand is the cache itself (what ``decode_ms.latent_attn`` finds)."""
+    from repro.kernels.latent_decode.kernel import latent_decode_kernel
+
+    def run(qc, cache, layer, pos):
+        return latent_decode_kernel(qc, cache, layer, pos, rank=512,
+                                    scale=192 ** -0.5)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+            [((16, 16, 576), jnp.float32),
+             ((26, 16, 576, 8192), jnp.bfloat16),
+             ((), jnp.int32), ((16,), jnp.int32)]]
+    compiled = jax.jit(run).lower(*args).compile()
+    (call,) = [ln for ln in compiled.as_text().splitlines()
+               if "tpu_custom_call" in ln]
+    assert "bf16[26,16,576,8192]" in call
+    assert compiled.memory_analysis().temp_size_in_bytes == 0

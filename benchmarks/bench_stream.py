@@ -1,4 +1,4 @@
-"""Streaming fused pipeline + fused-scan engine vs batch replay.
+"""Streaming fused pipeline vs batch replay.
 
 The batch path materializes every intermediate at full-run width: the
 regridded (streams x grid) blocks (twice — estimate pass and corrected
@@ -6,23 +6,18 @@ pass), the (devices x sensors x grid) fusion stack and the fused series
 before integration.  The streaming stage pipeline
 (``fleet.pipeline.StreamingFusedPipeline``) holds one (streams x chunk)
 window, a fixed tail and the (devices x phases x patterns) accumulators
-instead, so its working set is independent of run length.  The fused-
-scan engine (``engine="scan"``) replays the same chain as ONE jitted
-``lax.scan`` — no per-window dispatch, no per-stage jit boundaries.
+instead, so its working set is independent of run length.
 
-Reported: wall time + throughput for all three paths, measured host
+Reported: wall time + throughput for both paths, measured host
 peak (tracemalloc around each run — the batch path's big intermediates
 cross the numpy boundary), the deterministic working-set footprint of
 the arrays each path must hold at once, and the measured multi-host
 wire bytes: one tracked single-participant collectives run counts the
 framed (frontier, lag/weight) bytes each window actually posts vs the
-pre-wire-format dense encoding (``WireStats``).  Parity for both
-streaming paths is pinned at <=1e-5 against batch replay (fixed
-delays, shared grid — the configuration the tier-1 suite also checks).
-Targets: >=3x lower peak memory, fused-scan throughput above the
-checked-in ``scan_thr`` floor (dispatch-bound machines see far more
-than compute-bound single-core runners — the floor is measured, see
-baseline.json), and >=10x smaller per-window collective payloads.
+pre-wire-format dense encoding (``WireStats``).  Streaming parity is
+pinned at <=1e-5 against batch replay (fixed delays, shared grid — the
+configuration the tier-1 suite also checks).  Targets: >=3x lower peak
+memory and >=10x smaller per-window collective payloads.
 """
 import time
 import tracemalloc
@@ -163,21 +158,8 @@ def run():
              for (nm, a, b), e in zip(phases, totals[d])]
             for d in range(N_DEVICES)]
 
-    # the fused-scan engine: same replay, one jitted lax.scan
-    from repro.fleet.config import (PipelineConfig, StreamConfig,
-                                    TrackConfig)
-    from repro.fleet.pipeline import attribute_energy_fused_streaming
-
-    def scan_path():
-        state["scan"] = attribute_energy_fused_streaming(
-            groups, phases, config=PipelineConfig(
-                stream=StreamConfig(grid=grid, chunk=CHUNK,
-                                    engine="scan"),
-                track=TrackConfig(delays=d_all)))
-
     batch_s, batch_peak = _timed_peak(batch_path, REPEAT)
     stream_s, stream_peak = _timed_peak(stream_path, REPEAT)
-    scan_s, scan_peak = _timed_peak(scan_path, REPEAT)
 
     # --- wire format: measured per-window collective bytes -------------
     # one tracked 4-participant run (untimed, in-process threads over
@@ -235,7 +217,6 @@ def run():
         return worst
 
     rel = _rel("stream")
-    scan_rel = _rel("scan")
 
     # --- deterministic working sets ------------------------------------
     f, s = rows.shape
@@ -252,16 +233,13 @@ def run():
     win_cols = CHUNK + tail + 2
     stream_ws = (2 * f * win_cols + 2 * f * max(CHUNK, 512)) * itm
     return {"batch_s": batch_s, "stream_s": stream_s,
-            "scan_s": scan_s,
             "batch_peak": batch_peak, "stream_peak": stream_peak,
-            "scan_peak": scan_peak,
-            "rel_err": rel, "scan_rel_err": scan_rel,
+            "rel_err": rel,
             "n_traces": n_traces, "grid_points": g_n,
             "n_windows": n_win,
             "batch_ws": batch_ws, "stream_ws": stream_ws,
             "batch_tps": n_traces / batch_s,
             "stream_tps": n_traces / stream_s,
-            "scan_tps": n_traces / scan_s,
             "wire_frames": ws.frames,
             "wire_payload_bytes": ws.payload_bytes,
             "wire_raw_bytes": ws.raw_bytes}
@@ -272,7 +250,6 @@ def main():
     mem_ratio = out["batch_peak"] / max(out["stream_peak"], 1)
     ws_ratio = out["batch_ws"] / max(out["stream_ws"], 1)
     thr_ratio = out["stream_tps"] / out["batch_tps"]
-    scan_thr = out["scan_tps"] / out["batch_tps"]
     payload_b = out["wire_payload_bytes"] / max(out["wire_frames"], 1)
     wire_ratio = out["wire_raw_bytes"] / max(out["wire_payload_bytes"],
                                              1)
@@ -285,30 +262,21 @@ def main():
     print(f"  streaming pipeline:   {out['stream_s']*1e3:8.2f} ms "
           f"({out['stream_tps']:7.1f} traces/s)  host peak "
           f"{out['stream_peak']/1e6:7.1f} MB")
-    print(f"  fused-scan engine:    {out['scan_s']*1e3:8.2f} ms "
-          f"({out['scan_tps']:7.1f} traces/s)  host peak "
-          f"{out['scan_peak']/1e6:7.1f} MB")
     print(f"  measured peak ratio x{mem_ratio:.1f}, working-set ratio "
-          f"x{ws_ratio:.1f}, throughput ratio x{thr_ratio:.2f}, "
-          f"fused-scan x{scan_thr:.2f}")
+          f"x{ws_ratio:.1f}, throughput ratio x{thr_ratio:.2f}")
     print(f"  streaming vs batch energies: max rel err "
-          f"{out['rel_err']:.2e} (fused-scan {out['scan_rel_err']:.2e})")
+          f"{out['rel_err']:.2e}")
     print(f"  wire format: {out['wire_frames']} frames, "
           f"{payload_b:.1f} B/window posted vs "
           f"{out['wire_raw_bytes']/max(out['wire_frames'],1):.1f} B "
           f"dense (x{wire_ratio:.1f} smaller)")
     assert out["rel_err"] <= 1e-5, \
         f"stream/batch parity {out['rel_err']:.2e} > 1e-5"
-    assert out["scan_rel_err"] <= 1e-5, \
-        f"scan/batch parity {out['scan_rel_err']:.2e} > 1e-5"
     if not smoke(False, True):
         assert mem_ratio >= 3.0, \
             f"peak-memory ratio x{mem_ratio:.1f} < x3"
         assert thr_ratio >= 0.5, \
             f"throughput ratio x{thr_ratio:.2f} < x0.5"
-        assert scan_thr >= thr_ratio, \
-            f"fused-scan x{scan_thr:.2f} slower than windowed " \
-            f"x{thr_ratio:.2f}"
         # at smoke scale the 12-byte frame header dominates the tiny
         # fleet's dense frames; the full fleet must clear x10 (the
         # smoke floor lives in baseline.json)
@@ -316,10 +284,9 @@ def main():
             f"wire payload only x{wire_ratio:.1f} smaller than " \
             f"dense < x10"
     derived = (f"mem_ratio=x{mem_ratio:.1f},ws_ratio=x{ws_ratio:.1f},"
-               f"thr_ratio=x{thr_ratio:.2f},scan_thr=x{scan_thr:.2f},"
+               f"thr_ratio=x{thr_ratio:.2f},"
                f"payload_b={payload_b:.1f},wire_ratio=x{wire_ratio:.1f},"
-               f"rel_err={out['rel_err']:.1e},"
-               f"scan_rel_err={out['scan_rel_err']:.1e}")
+               f"rel_err={out['rel_err']:.1e}")
     return us, derived
 
 

@@ -20,7 +20,7 @@ writes a markdown comparison table, and exits non-zero when
     key) must stay within max(10x its baseline value,
     ``--parity-floor``), or
   * a derived metric dropped below its checked-in floor: each entry in
-    a bench's ``floors`` map (e.g. fused-scan throughput ratio,
+    a bench's ``floors`` map (e.g. the peak-memory ratio or the
     collective wire-compression ratio) is a hard minimum on the
     current run's derived value.
 

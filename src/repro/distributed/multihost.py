@@ -347,9 +347,8 @@ def attribute_energy_fused_multihost(local_groups, phases, *, shard,
     all hosts return the SAME fleet-wide result — one ``[PhaseEnergy]``
     per GLOBAL device group.  ``config`` is the same
     ``fleet.config.PipelineConfig`` bundle the single-host entry point
-    takes (the scan engine is single-host only — multi-host runs are
-    always windowed); the pre-config flat kwargs still resolve
-    bit-identically but emit a ``DeprecationWarning``.
+    takes; the pre-config flat kwargs still resolve bit-identically
+    but emit a ``DeprecationWarning``.
 
     Every origin the float32 packing depends on (shared t0, the counter
     sub-pack origin, the output grid, the replay span and cadence) is
@@ -403,14 +402,11 @@ def attribute_energy_fused_multihost(local_groups, phases, *, shard,
                                       stream_row_windows)
     cfg = resolve_config(config, legacy,
                          "attribute_energy_fused_multihost")
-    assert cfg.stream.engine == "windowed", \
-        "multi-host attribution drives the windowed engine only"
     chunk = cfg.stream.chunk
     grid, grid_step = cfg.stream.grid, cfg.stream.grid_step
     dtype, var_floor = cfg.stream.dtype, cfg.stream.var_floor
     use_t_measured = cfg.stream.use_t_measured
     interpret, use_kernel = cfg.stream.interpret, cfg.stream.use_kernel
-    host = cfg.stream.host
     track, delays = cfg.track.track, cfg.track.delays
     window, hop = cfg.track.window, cfg.track.hop
     max_lag, ema = cfg.track.max_lag, cfg.track.ema
@@ -501,7 +497,7 @@ def attribute_energy_fused_multihost(local_groups, phases, *, shard,
         max_lag=max_lag, ema=ema, tail=tail, var_floor=var_floor,
         collectives=collectives, shard=shard, record=record,
         dtype=dtype, interpret=interpret, use_kernel=use_kernel,
-        host=host, health=health, registry=registry,
+        health=health, registry=registry,
         health_names=health_names, dq_policy=dq_policy)
     span = (collectives.allreduce_min(
                 float(rows.times[:n, 0].astype(np.float64).min())),

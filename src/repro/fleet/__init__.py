@@ -13,11 +13,7 @@ subsystem is the batched counterpart of ``repro.core.reconstruction`` /
                Reconstruct -> AlignTrack -> Regrid/Fuse ->
                PhaseAttribute, every stage one (fleet, chunk) window +
                an explicit carry dataclass; online delay tracking and
-               streaming fused attribution live here, plus the
-               fused-scan engine (``attribute_totals_fused_scan``)
-               that replays the same chain as ONE jitted ``lax.scan``
-               with a donated carry — the per-window chain stays the
-               parity oracle
+               streaming fused attribution live here
   streaming  — ``FleetStream`` / ``StreamingPhaseAccumulator``: thin
                pre-built two-stage pipelines (fused ``fleet_attribute``
                / ``phase_integrate`` kernels), O(fleet × chunk) device
@@ -45,10 +41,8 @@ from repro.fleet.pipeline import (AlignTrackStage,  # noqa: F401
                                   DataQualityError, DataQualityPolicy,
                                   IngestStage, PhaseIntegrateStage,
                                   ReconstructStage, RegridFuseStage,
-                                  ScanResult, StreamPipeline,
-                                  StreamingFusedPipeline,
+                                  StreamPipeline, StreamingFusedPipeline,
                                   attribute_energy_fused_streaming,
-                                  attribute_totals_fused_scan,
                                   pack_stream_rows)
 from repro.fleet.api import (attribute_energy_fleet,  # noqa: F401
                              attribute_energy_fused, fleet_power_series)

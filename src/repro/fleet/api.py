@@ -4,8 +4,7 @@
 and ``attribute_energy_fleet`` replaces ``[attribute_energy(tr, phases)
 for tr in ...]`` for cumulative-energy traces; the host loops remain the
 parity oracles (tests pin fleet == host).  For fused multi-sensor
-streaming (and its single-scan fast path, ``engine="scan"``) see
-``pipeline.attribute_energy_fused_streaming``.
+streaming see ``pipeline.attribute_energy_fused_streaming``.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@
 One frozen dataclass per concern replaces the ~22 keyword arguments
 the streaming API had accreted:
 
-  * :class:`StreamConfig` — chunking, output grid, dtype, engine and
+  * :class:`StreamConfig` — chunking, output grid, dtype and
     execution knobs;
   * :class:`TrackConfig` — the AlignTrack window geometry and EMA;
   * :class:`CheckpointConfig` — elastic carry checkpoints;
@@ -27,17 +27,15 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class StreamConfig:
-    """Chunking, output grid and execution engine."""
+    """Chunking, output grid and execution knobs."""
     chunk: int = 1024            # replay window width (columns)
     grid: object = None          # absolute output grid (pins parity)
     grid_step: float = None      # grid step (default: half cadence)
     dtype: object = np.float32   # device dtype for the packed rows
-    engine: str = "windowed"     # "windowed" (oracle) | "scan" (fast)
     var_floor: float = 0.25      # fusion variance floor (W^2)
     use_t_measured: bool = True  # sensor timestamps vs read times
     interpret: bool = None       # Pallas interpret-mode override
     use_kernel: bool = None      # force/forbid the fused kernels
-    host: bool = False           # host (numpy) execution
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,7 +52,7 @@ class TrackConfig:
 
 @dataclasses.dataclass(frozen=True)
 class CheckpointConfig:
-    """Elastic carry checkpoints (windowed engine)."""
+    """Elastic carry checkpoints."""
     dir: str = None              # checkpoint directory (None = off)
     every: int = 0               # checkpoint every K replay windows
     resume: bool = False         # reload the newest complete one
@@ -76,12 +74,10 @@ LEGACY_FIELDS = {
     "grid": ("stream", "grid"),
     "grid_step": ("stream", "grid_step"),
     "dtype": ("stream", "dtype"),
-    "engine": ("stream", "engine"),
     "var_floor": ("stream", "var_floor"),
     "use_t_measured": ("stream", "use_t_measured"),
     "interpret": ("stream", "interpret"),
     "use_kernel": ("stream", "use_kernel"),
-    "host": ("stream", "host"),
     "track": ("track", "track"),
     "delays": ("track", "delays"),
     "window": ("track", "window"),

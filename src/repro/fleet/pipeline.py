@@ -51,15 +51,6 @@ packed traces through this chain in fixed-width chunks and matches the
 batch path; ``FleetStream`` / ``StreamingPhaseAccumulator``
 (fleet/streaming.py) are thin pre-built two-stage pipelines over the
 same Ingest/attribute stages.
-
-The FUSED-SCAN engine (``attribute_totals_fused_scan``, or
-``engine="scan"`` on the replay entry point) collapses the per-window
-chain into one jitted ``lax.scan`` over fixed-size slot blocks with a
-donated carry: the host plans the replay (window edges, delay schedule,
-emit-frontier slot ranges) and the device executes every
-Reconstruct/Regrid/Fuse/PhaseAttribute step without per-window Python
-dispatch.  The per-window path stays the parity oracle (<= 1e-5,
-tracked and untracked) and the only multi-host driver.
 """
 from __future__ import annotations
 
@@ -437,12 +428,11 @@ class ReconstructStage:
     Stateless given closed windows (the carry edge closes the boundary
     interval, so dE telescopes across chunks with no extra state).
     ``kind_row`` marks counter rows; power rows pass through.  Device
-    path runs the ``power_reconstruct_rows`` Pallas kernel; the float64
-    host mirror computes the same formula in numpy.
+    path runs the ``power_reconstruct_rows`` Pallas kernel.
     """
 
     def __init__(self, kind_row, wrap_row=None, *, interpret=None,
-                 use_kernel: bool = True, host: bool = False):
+                 use_kernel: bool = True):
         self.kind_row = np.asarray(kind_row, bool).reshape(-1)
         f = len(self.kind_row)
         self.wrap_row = (np.zeros((f, 1), np.float64) if wrap_row is None
@@ -450,7 +440,6 @@ class ReconstructStage:
                                          np.float64).reshape(f, 1))
         self.interpret = auto_interpret(interpret)
         self.use_kernel = use_kernel
-        self.host = host
 
     def reset(self):
         return self
@@ -459,16 +448,9 @@ class ReconstructStage:
         t, v = chunk.times, chunk.values
         if not self.kind_row.any():
             return chunk
-        if self.host:
-            from repro.kernels.power_reconstruct.ref import wrapped_diff
-            de = wrapped_diff(v.astype(np.float64),
-                              self.wrap_row, xp=np)
-            dt = np.maximum(np.diff(t.astype(np.float64), axis=1), 1e-12)
-            power = np.pad(de / dt, ((0, 0), (1, 0)))
-        else:
-            power = np.asarray(_reconstruct_window(
-                t, v, self.wrap_row.astype(t.dtype),
-                interpret=self.interpret, use_kernel=self.use_kernel))
+        power = np.asarray(_reconstruct_window(
+            t, v, self.wrap_row.astype(t.dtype),
+            interpret=self.interpret, use_kernel=self.use_kernel))
         out_v = np.where(self.kind_row[:, None], power.astype(v.dtype), v)
         return ClosedWindow(times=t, values=out_v, t_first=chunk.t_first)
 
@@ -566,13 +548,13 @@ class _RowTail:
 
 
 def _query_grid(rows_t, rows_v, grid64, delays64, t_first, *,
-                interpret, use_kernel, host):
+                interpret, use_kernel):
     """Hold-resample all rows at ``grid + delay[row]`` -> (vals, mask).
 
-    Device path: the ``grid_resample`` kernel/op (queries formed in the
-    row dtype, exactly as the batch ``regrid_rows`` does, so streamed
-    and batch lookups compare the SAME float32 values at hold
-    discontinuities).  host=True: the float64 numpy mirror.
+    Runs the ``grid_resample`` kernel/op with queries formed in the row
+    dtype, exactly as the batch ``regrid_rows`` does, so streamed and
+    batch lookups compare the SAME float32 values at hold
+    discontinuities.
     """
     f, s = rows_t.shape
     dtype = rows_t.dtype
@@ -580,31 +562,23 @@ def _query_grid(rows_t, rows_v, grid64, delays64, t_first, *,
     first_row = np.zeros((f, 1), np.int32)
     g = np.asarray(grid64, np.float64).astype(dtype)
     d = np.asarray(delays64, np.float64).astype(dtype).reshape(f, 1)
-    if host:
-        from repro.kernels.grid_resample.ref import grid_resample_ref
-        out, mask = grid_resample_ref(
-            rows_t.astype(np.float64), rows_v.astype(np.float64),
-            n_row, first_row, g.reshape(-1, 1).astype(np.float64),
-            d.astype(np.float64), mode="hold", xp=np)
-        ge = g[None, :].astype(np.float64) + d.astype(np.float64)
-    else:
-        import jax.numpy as jnp
-        from repro.kernels.grid_resample.ops import grid_resample
-        # pad the query count to a coarse multiple (replicating the last
-        # point) so the per-window jit sees a handful of shapes instead
-        # of one per distinct frontier advance
-        gq = len(g)
-        pad = (-gq) % 256
-        g_in = np.concatenate([g, np.full((pad,), g[-1], dtype)]) \
-            if pad else g
-        out, mask = grid_resample(jnp.asarray(rows_t), jnp.asarray(rows_v),
-                                  n_row, first_row, jnp.asarray(g_in),
-                                  jnp.asarray(d[:, 0]), mode="hold",
-                                  interpret=interpret,
-                                  use_kernel=use_kernel)
-        out = np.asarray(out)[:, :gq]
-        mask = np.asarray(mask)[:, :gq]
-        ge = g[None, :] + d                  # row-dtype query, as the op
+    import jax.numpy as jnp
+    from repro.kernels.grid_resample.ops import grid_resample
+    # pad the query count to a coarse multiple (replicating the last
+    # point) so the per-window jit sees a handful of shapes instead
+    # of one per distinct frontier advance
+    gq = len(g)
+    pad = (-gq) % 256
+    g_in = np.concatenate([g, np.full((pad,), g[-1], dtype)]) \
+        if pad else g
+    out, mask = grid_resample(jnp.asarray(rows_t), jnp.asarray(rows_v),
+                              n_row, first_row, jnp.asarray(g_in),
+                              jnp.asarray(d[:, 0]), mode="hold",
+                              interpret=interpret,
+                              use_kernel=use_kernel)
+    out = np.asarray(out)[:, :gq]
+    mask = np.asarray(mask)[:, :gq]
+    ge = g[None, :] + d                      # row-dtype query, as the op
     span = ge >= np.asarray(t_first, np.float64).astype(dtype)[:, None]
     mask = mask & span
     return np.where(mask, out, 0).astype(dtype, copy=False), mask
@@ -693,8 +667,7 @@ class AlignTrackStage:
                  hop: int = 512, max_lag: int = 64, ema: float = 0.5,
                  min_corr: float = 0.2, min_fill: int = None,
                  tail: int = 256, delay0=None, collectives=None,
-                 shard=None, interpret=None, use_kernel: bool = True,
-                 host: bool = False):
+                 shard=None, interpret=None, use_kernel: bool = True):
         assert reference is not None or groups is not None, \
             "AlignTrack needs a reference schedule or group structure"
         self.n_streams = n_streams
@@ -714,17 +687,15 @@ class AlignTrackStage:
             assert shard is not None, \
                 "synchronized tracking needs the HostShard (global " \
                 "row ids place this host's lags in the fleet vector)"
-            assert not host and use_kernel is not False, \
+            assert use_kernel is not False, \
                 "synchronized tracking requires the kernel scorer — " \
-                "the host mirror's / jnp reference's full-fleet " \
-                "matmul ignores the pinned row tile and is not " \
-                "partition-invariant"
+                "the jnp reference's full-fleet matmul ignores the " \
+                "pinned row tile and is not partition-invariant"
             assert self.min_corr > 0.0, \
                 "synchronized tracking needs min_corr > 0 (the zero " \
                 "frames of hop-less windows must never pass the gate)"
         self.interpret = auto_interpret(interpret)
         self.use_kernel = use_kernel
-        self.host = host
         self._tail = _RowTail(tail)
         self._delay0 = (np.zeros((0,)) if delay0 is None
                         else np.asarray(delay0, np.float64))
@@ -812,8 +783,7 @@ class AlignTrackStage:
                                      np.zeros(rows_t.shape[0]),
                                      chunk.t_first,
                                      interpret=self.interpret,
-                                     use_kernel=self.use_kernel,
-                                     host=self.host)
+                                     use_kernel=self.use_kernel)
             k = len(idx)
             if k >= self.window:
                 c.ring_v = vals[:, -self.window:]
@@ -830,9 +800,7 @@ class AlignTrackStage:
         return chunk
 
     def _estimate(self):
-        from repro.align.delay import (estimate_delays,
-                                       estimate_delays_host,
-                                       stream_reference)
+        from repro.align.delay import estimate_delays, stream_reference
         c = self.carry
         n = self.n_streams
         w_idx = np.arange(c.next_slot - self.window, c.next_slot)
@@ -844,10 +812,6 @@ class AlignTrackStage:
         uk = True if self.use_kernel is None else self.use_kernel
 
         def run(vals, mask, ref):
-            if self.host:
-                return estimate_delays_host(vals.astype(np.float64),
-                                            mask, ref, step=self.step,
-                                            max_lag=self.max_lag)
             # the row tile is PINNED (ROW_ALIGN) so each row's score is
             # bit-identical however many rows this host scores with it
             # — the partition-invariance rule the multi-host tracker
@@ -970,7 +934,7 @@ class RegridFuseStage:
                  grid_step: float, delays=None, align=None,
                  tail: int = 256, var_floor: float = 0.25,
                  collectives=None, record: bool = False,
-                 interpret=None, use_kernel=None, host: bool = False,
+                 interpret=None, use_kernel=None,
                  dq_policy: DataQualityPolicy = None):
         self.group_sizes = list(group_sizes)
         self.n_streams = int(sum(self.group_sizes))
@@ -985,7 +949,6 @@ class RegridFuseStage:
         self.emitted: list = []
         self.interpret = auto_interpret(interpret)
         self.use_kernel = use_kernel
-        self.host = host
         self._tail = _RowTail(tail)
         self.carry = FuseCarry(next_slot=0,
                                n_k=np.zeros((self.n_streams,)),
@@ -1067,8 +1030,7 @@ class RegridFuseStage:
         self._tail.check_reach(grid64[0] + delays, "Regrid/Fuse")
         vals, mask = _query_grid(rows_t, rows_v, grid64, delays, t_first,
                                  interpret=self.interpret,
-                                 use_kernel=self.use_kernel,
-                                 host=self.host)
+                                 use_kernel=self.use_kernel)
         n = self.n_streams
         vals, mask = vals[:n], mask[:n]
         # coverage-pattern accounting: which slots each stream covered
@@ -1826,12 +1788,10 @@ def _replay_window_plan(rows: StreamRows, chunk: int = 1024, *,
                         span=None, cadence: float = None):
     """Shared window-edge math for replay: -> (n_win, idx).
 
-    ONE definition of the time-aligned replay boundaries, used by both
-    ``stream_row_windows`` (the per-window streaming replay) and the
-    fused-scan planner (``attribute_totals_fused_scan``) — the scan
-    path's emit frontiers reproduce the per-window path's only because
-    both walk identical window edges.  ``idx[i, w]`` is row i's first
-    sample index in window w (idx[:, -1] == S).
+    ONE definition of the time-aligned replay boundaries, shared by
+    ``stream_row_windows`` and the replay entry points, so every replay
+    of the same rows walks identical window edges.  ``idx[i, w]`` is
+    row i's first sample index in window w (idx[:, -1] == S).
     """
     f, s = rows.shape
     n = rows.n_streams
@@ -1922,7 +1882,7 @@ class StreamingFusedPipeline:
                  ema: float = 0.5, min_corr: float = 0.2, tail: int = 256,
                  var_floor: float = 0.25, collectives=None, shard=None,
                  record: bool = False, dtype=np.float32,
-                 interpret=None, use_kernel=None, host: bool = False,
+                 interpret=None, use_kernel=None,
                  health=None, registry=None, health_names=None,
                  meter=None, dq_policy: DataQualityPolicy = None):
         self.group_sizes = list(group_sizes)
@@ -1952,8 +1912,7 @@ class StreamingFusedPipeline:
         self.ingest = IngestStage(n, mode="sanitize", kind_row=kr,
                                   dq_policy=dq_policy)
         self.reconstruct = ReconstructStage(
-            kr, wp, interpret=interpret, use_kernel=uk_bool,
-            host=host)
+            kr, wp, interpret=interpret, use_kernel=uk_bool)
         self.align = None
         if track:
             self.align = AlignTrackStage(
@@ -1962,13 +1921,13 @@ class StreamingFusedPipeline:
                 window=window, hop=hop, max_lag=max_lag, ema=ema,
                 min_corr=min_corr, tail=tail, delay0=delays,
                 collectives=collectives, shard=shard,
-                interpret=interpret, use_kernel=use_kernel, host=host)
+                interpret=interpret, use_kernel=use_kernel)
         self.fuse = RegridFuseStage(
             self.group_sizes, grid_origin=grid_origin,
             grid_step=grid_step, delays=delays, align=self.align,
             tail=tail, var_floor=var_floor, collectives=collectives,
             record=record, interpret=interpret,
-            use_kernel=use_kernel, host=host, dq_policy=dq_policy)
+            use_kernel=use_kernel, dq_policy=dq_policy)
         self.attr = FusedPhaseAttributeStage(phases, self.group_sizes,
                                              self.fuse,
                                              collectives=collectives,
@@ -2645,454 +2604,6 @@ def _published_steps(d) -> set:
             and not p.name.endswith(".tmp")}
 
 
-# ---------------------------------------------------------------------------
-# The fused-scan engine: the whole replay as ONE jitted lax.scan
-# ---------------------------------------------------------------------------
-
-def _scan_closed_rows(rows: StreamRows, *, interpret, use_kernel, host):
-    """Full-run closed rows: -> (t_aug, v_aug, t_first64).
-
-    The per-window chain re-derives these incrementally (Ingest seeds a
-    zero-width carry edge, Reconstruct turns each window's counter
-    intervals into dE/dt); over a full replay the union of those
-    windows is exactly the packed rows with the seed column prepended —
-    equal-time replica columns the replay pads in are search-invisible
-    to the hold lower bound, and dE/dt is interval-local so it
-    telescopes — so one reconstruction over the full rows reproduces
-    every per-window query's source samples bit-for-bit.
-    """
-    t = rows.times
-    v = rows.values
-    kind = np.asarray(rows.kind_row, bool).reshape(-1)
-    t_aug = np.concatenate([t[:, :1], t], axis=1)
-    v_aug = np.concatenate([v[:, :1], v], axis=1)
-    # final t_first, same convention as IngestStage: counters open at
-    # the first strict advance past the seed, power rows at the seed
-    t64 = t_aug.astype(np.float64)
-    adv = t64 > t64[:, :1]
-    j = np.argmax(adv, axis=1)
-    tf = np.where(adv.any(axis=1), t64[np.arange(len(j)), j], np.inf)
-    t_first = np.where(kind, tf, t64[:, 0])
-    if kind.any():
-        wrap = np.zeros((t.shape[0], 1), t_aug.dtype)
-        if host:
-            from repro.kernels.power_reconstruct.ref import wrapped_diff
-            de = wrapped_diff(v_aug.astype(np.float64),
-                              wrap.astype(np.float64), xp=np)
-            dt = np.maximum(np.diff(t_aug.astype(np.float64), axis=1),
-                            1e-12)
-            power = np.pad(de / dt, ((0, 0), (1, 0)))
-        else:
-            power = np.asarray(_reconstruct_window(
-                t_aug, v_aug, wrap, interpret=interpret,
-                use_kernel=True if use_kernel is None else use_kernel))
-        v_aug = np.where(kind[:, None], power.astype(v_aug.dtype), v_aug)
-    return t_aug, v_aug, t_first
-
-
-def _scan_track_delays(rows: StreamRows, rows_t, rows_v, t_first,
-                       last_t, n_win: int, *, group_sizes, reference,
-                       grid_step: float, window: int, hop: int,
-                       max_lag: int, ema: float, min_corr: float,
-                       min_fill, delay0, interpret, use_kernel, host):
-    """AlignTrack replayed on the host: -> (delays_win, history).
-
-    The online tracker's ring is a sliding view of one uniform track
-    grid, filled through the same hold resample the regrid uses — so
-    instead of updating a ring per window, the scan planner resamples
-    the full reconstructed rows at EVERY track slot in one batched
-    query (the AlignTrack-merged-into-Regrid step of the fused scan)
-    and slices each hop's window out of it.  The hop schedule, the
-    xcorr scorer (row tile pinned to ``ROW_ALIGN``), the ``min_corr``
-    gate and the EMA fold are the per-window tracker's own arithmetic
-    on bit-identical inputs, so ``delays_win[w]`` equals the delay
-    vector the per-window path would apply to replay window ``w``.
-    """
-    from repro.align.delay import (estimate_delays, estimate_delays_host,
-                                   stream_reference)
-    f = rows.shape[0]
-    n = rows.n_streams
-    step = float(grid_step)
-    origin = float(rows.times[:n, 0].astype(np.float64).min())
-    delay = np.zeros((f,), np.float64)
-    if delay0 is not None:
-        d0 = np.asarray(delay0, np.float64).reshape(-1)
-        delay[:len(d0)] = d0
-    seen = np.zeros((f,), bool)
-    min_fill = window // 2 if min_fill is None else int(min_fill)
-
-    # hop schedule: which replay windows fire a re-estimate (same
-    # -0.01-step fill margin as the online ring)
-    next_slot, last_est = 0, 0
-    fires = {}                       # window index -> ring frontier slot
-    for w in range(n_win):
-        frontier = float(last_t[:, w].min())
-        hi = int(np.floor((frontier - origin) / step - 0.01))
-        if hi >= next_slot:
-            next_slot = hi + 1
-        if next_slot - last_est >= hop and next_slot >= min_fill:
-            fires[w] = next_slot
-            last_est = next_slot
-
-    delays_win = np.empty((n_win, f), np.float64)
-    history = []
-    if not fires:
-        delays_win[:] = delay[None, :]
-        return delays_win, history
-
-    # one batched resample at every track slot the ring will ever hold
-    # (slots < 0 stay the ring's zero-initialized prefix)
-    max_slot = max(fires.values())
-    grid64 = origin + step * np.arange(max_slot)
-    vals, mask = _query_grid(rows_t, rows_v, grid64, np.zeros((f,)),
-                             t_first, interpret=interpret,
-                             use_kernel=use_kernel, host=host)
-    uk = True if use_kernel is None else use_kernel
-
-    def run(v_win, m_win, ref):
-        if host:
-            return estimate_delays_host(v_win.astype(np.float64), m_win,
-                                        ref, step=step, max_lag=max_lag)
-        return estimate_delays(v_win, m_win.astype(v_win.dtype), ref,
-                               step=step, max_lag=max_lag,
-                               interpret=interpret, use_kernel=uk,
-                               block_rows=ROW_ALIGN)
-
-    for w in range(n_win):
-        ns = fires.get(w)
-        if ns is not None:
-            w_idx = np.arange(ns - window, ns)
-            v_win = np.zeros((f, window), vals.dtype)
-            m_win = np.zeros((f, window), bool)
-            pos = w_idx >= 0
-            v_win[:, pos] = vals[:, w_idx[pos]]
-            m_win[:, pos] = mask[:, w_idx[pos]]
-            times64 = origin + step * w_idx
-            raw = np.zeros((f,))
-            peak = np.zeros((f,))
-            if reference is not None:
-                ref = np.asarray(reference(times64), np.float64)
-                est = run(v_win, m_win, ref)
-                raw, peak = np.asarray(est.delay_s), \
-                    np.asarray(est.peak_corr)
-            else:
-                lo = 0
-                for g in group_sizes:
-                    hi_g = lo + g
-                    ref = stream_reference(v_win[lo], m_win[lo])
-                    est = run(v_win[lo:hi_g], m_win[lo:hi_g], ref)
-                    raw[lo:hi_g] = est.delay_s
-                    peak[lo:hi_g] = est.peak_corr
-                    lo = hi_g
-            good = peak >= min_corr
-            good[n:] = False              # padding rows never track
-            a = np.where(seen, ema, 1.0)  # first estimate: direct
-            delay = np.where(good, (1 - a) * delay + a * raw, delay)
-            seen = seen | good
-            history.append(DelayTrackPoint(
-                t_lo=float(times64[0]), t_hi=float(times64[-1]),
-                t_center=float(0.5 * (times64[0] + times64[-1])),
-                raw=raw[:n].copy(), ema=delay[:n].copy(),
-                peak=peak[:n].copy()))
-        delays_win[w] = delay
-    return delays_win, history
-
-
-@functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("block", "width"))
-def _fused_scan_steps(carry, xs, rows_t, rows_v, t_first32, t_last32,
-                      gidx, gmask, phases, origin, step, *, block,
-                      width):
-    """Regrid + fuse + phase-attribute over all emit steps in ONE scan.
-
-    Traced under x64: queries are still formed in the row dtype
-    (float32 — bit-identical lookups to the per-window ``_query_grid``)
-    while the fusion statistics and phase integrals accumulate in
-    float64, exactly like the host-side stage carries.  The carry
-    (donated) holds the whole pipeline state: per-stream (n_k, ssr),
-    per-device (t_prev, seen) bridging, and the per-(device, pattern,
-    phase, stream) integrals the windowed ``FusedPhaseAttributeStage``
-    keeps as dicts — here a dense (D, 2^K, P, K) block so every window
-    is one einsum.
-
-    Each step's hold lookup searches only a ``width``-column slice of
-    every row, starting at the host-planned per-(step, row) offset in
-    ``xs`` — the planner proves the slice covers every lower bound the
-    step's queries can hit (rows are time-sorted and the emit frontier
-    moves monotonically), so the sliced search returns the SAME indices
-    as a full-row search at a fraction of the work.
-    """
-    import jax.numpy as jnp
-    f, s = rows_t.shape
-    iota = jnp.arange(block)
-    k = gidx.shape[1]
-    slice_row = jax.vmap(
-        lambda row, s0: jax.lax.dynamic_slice(row, (s0,), (width,)))
-
-    def body(c, x):
-        n_k, ssr, t_prev, seen, integrals = c
-        lo, cnt, st, d32 = x
-        grid64 = origin + step * (lo + iota)
-        g32 = grid64.astype(rows_t.dtype)
-        ge = g32[None, :] + d32[:, None]      # row-dtype, as the op
-        blk_t = slice_row(rows_t, st)
-        blk_v = slice_row(rows_v, st)
-        idx = jax.vmap(lambda a, v: jnp.searchsorted(
-            a, v, side="left"))(blk_t, ge)
-        out = jnp.take_along_axis(blk_v, jnp.clip(idx, 0, width - 1),
-                                  axis=1)
-        mask = (ge >= t_first32[:, None]) & (ge <= t_last32[:, None]) \
-            & (iota < cnt)[None, :]
-        vals = jnp.where(mask, out, 0.0)
-        # per-group fusion statistics (the RegridFuse carry update)
-        vg = vals[gidx].astype(jnp.float64) * gmask[:, :, None]
-        mg = mask[gidx].astype(jnp.float64) * gmask[:, :, None]
-        cnt_g = mg.sum(axis=1)                               # (D, B)
-        m0 = (vg * mg).sum(axis=1) / jnp.maximum(cnt_g, 1.0)
-        resid = (vg - m0[:, None, :]) * mg
-        n_k = n_k.at[gidx].add(mg.sum(axis=2))
-        ssr = ssr.at[gidx].add((resid * resid).sum(axis=2))
-        # dense t_lo bridging (invalid slots fold into the next valid)
-        anyv = cnt_g > 0
-        gt = jnp.where(anyv, grid64[None, :], -jnp.inf)
-        run = jax.lax.cummax(gt, axis=1)
-        prev = jnp.concatenate(
-            [jnp.full((gt.shape[0], 1), -jnp.inf), run[:, :-1]], axis=1)
-        t_lo = jnp.maximum(prev, t_prev[:, None])
-        first_ever = anyv & (~seen[:, None]) \
-            & (jnp.cumsum(anyv, axis=1) == 1)
-        t_lo = jnp.where(first_ever, grid64[None, :], t_lo)
-        # overlap of [t_lo, grid] with phase [a, b] as F(grid) - F(t_lo)
-        # where F(x) = clip(x - a, 0, b - a): the F(grid) term is
-        # device-independent, so only F(t_lo) costs (D, P, B) work
-        a = phases[:, 0]
-        blen = jnp.maximum(phases[:, 1] - a, 0.0)
-        f_g = jnp.clip(grid64[None, :] - a[:, None], 0.0,
-                       blen[:, None])                        # (P, B)
-        f_lo = jnp.clip(t_lo[:, None, :] - a[None, :, None], 0.0,
-                        blen[None, :, None])
-        # no anyv mask needed: invalid slots carry zero fusion weight
-        # (vg * mg == 0) and the clip keeps f_lo finite even at -inf
-        ov = f_g[None, :, :] - f_lo                          # (D, P, B)
-        # coverage-pattern one-hot: the windowed dict-of-patterns as a
-        # dense (D, 2^K, P, K) accumulate.  The pattern is built from
-        # integer bits: a TPU emulates float64, and there 2.0 ** j is
-        # inexact, so float patterns never equal the one-hot's codes
-        bits = (mg > 0).astype(jnp.int32) \
-            << jnp.arange(k, dtype=jnp.int32)[None, :, None]
-        pat = bits.sum(axis=1, dtype=jnp.int32)              # (D, B)
-        qn = integrals.shape[1]
-        onehot = (pat[:, None, :]
-                  == jnp.arange(qn, dtype=jnp.int32)[None, :, None])
-        integrals = integrals + jnp.einsum(
-            'dqj,dpj,dkj->dqpk', onehot.astype(jnp.float64), ov,
-            vg * mg)
-        t_prev = jnp.maximum(t_prev, run[:, -1])
-        seen = seen | anyv.any(axis=1)
-        return (n_k, ssr, t_prev, seen, integrals), None
-
-    carry, _ = jax.lax.scan(body, carry, xs)
-    return carry
-
-
-@dataclasses.dataclass
-class ScanResult:
-    """What the fused-scan engine hands back (host numpy)."""
-    totals: np.ndarray         # (n_devices, n_phases) fused joules
-    weights: np.ndarray        # (n_streams,) end-of-run IVW weights
-    delays: np.ndarray         # (n_streams,) final per-stream delay
-    history: list              # [DelayTrackPoint] (tracked mode)
-    n_steps: int               # scan steps executed
-    n_slots: int               # grid slots emitted
-
-
-def attribute_totals_fused_scan(rows: StreamRows, group_sizes, phases,
-                                *, grid_origin: float, grid_step: float,
-                                t_end: float = None, chunk: int = 1024,
-                                delays=None, reference=None,
-                                track: bool = None, window: int = 2048,
-                                hop: int = 512, max_lag: int = 64,
-                                ema: float = 0.5, min_corr: float = 0.2,
-                                min_fill: int = None,
-                                var_floor: float = 0.25,
-                                scan_block: int = 512, interpret=None,
-                                use_kernel=None,
-                                host: bool = False) -> ScanResult:
-    """The streaming chain fused into one jitted ``lax.scan``.
-
-    Plans on the host (replay window edges via ``_replay_window_plan``
-    — the SAME edge math the per-window replay walks — then the delay
-    schedule and the emit-frontier slot ranges), and executes every
-    Reconstruct -> Regrid/Fuse -> PhaseAttribute step as one scan over
-    fixed-size slot blocks with a donated carry: no per-window Python
-    dispatch, no per-stage jit boundaries, no host round-trips in the
-    hot loop.  AlignTrack's ring fill is merged into the same batched
-    hold-resample the regrid uses (``_scan_track_delays``), which the
-    emit frontier allows because every ring slot is behind it by
-    construction.  Single-host replay only — the multi-host path keeps
-    the per-window stages (its frontier all-reduces are per-window by
-    contract); the per-window path also remains the parity oracle
-    (streamed vs fused-scan <= 1e-5, tracked and untracked).
-
-    Arguments mirror ``StreamingFusedPipeline``; ``scan_block`` is the
-    slots-per-step width (compiled shape).  Returns a ``ScanResult``.
-    """
-    import jax.numpy as jnp
-    group_sizes = list(group_sizes)
-    n = int(sum(group_sizes))
-    assert n == rows.n_streams, (n, rows.n_streams)
-    k_max = int(max(group_sizes))
-    assert k_max <= 8, \
-        f"fused scan holds 2^k coverage patterns per device (k={k_max})"
-    f = rows.shape[0]
-    if track is None:
-        track = delays is None
-    interpret = auto_interpret(interpret)
-    origin = float(grid_origin)
-    step = float(grid_step)
-
-    t_aug, v_aug, t_first = _scan_closed_rows(
-        rows, interpret=interpret, use_kernel=use_kernel, host=host)
-    sent_t = np.full((f, 1), -np.inf, t_aug.dtype)
-    sent_v = np.zeros((f, 1), v_aug.dtype)
-    rows_t = np.concatenate([sent_t, t_aug], axis=1)
-    rows_v = np.concatenate([sent_v, v_aug], axis=1)
-
-    emits = []
-    next_slot = 0
-    if track:
-        n_win, idx = _replay_window_plan(rows, chunk)
-        cols = np.maximum(idx[:, 1:] - 1, np.maximum(idx[:, :-1] - 1, 0))
-        last_t = np.take_along_axis(rows.times, cols,
-                                    axis=1).astype(np.float64)[:n]
-        delays_win, history = _scan_track_delays(
-            rows, rows_t, rows_v, t_first, last_t, n_win,
-            group_sizes=group_sizes, reference=reference,
-            grid_step=step, window=window, hop=hop, max_lag=max_lag,
-            ema=ema, min_corr=min_corr, min_fill=min_fill,
-            delay0=delays, interpret=interpret, use_kernel=use_kernel,
-            host=host)
-        # emit schedule: identical frontier floors/margins to RegridFuse
-        for w in range(n_win):
-            frontier = float((last_t[:, w] - delays_win[w, :n]).min())
-            hi = int(np.floor((frontier - origin) / step - 0.01))
-            if hi >= next_slot:
-                emits.append((next_slot, hi, w))
-                next_slot = hi + 1
-        if t_end is None:
-            t_end = float((last_t[:, -1] - delays_win[-1, :n]).max())
-    else:
-        # untracked fast path: the delay vector is constant, so every
-        # slot's contribution is window-independent and the per-window
-        # emit partition only regroups the same f64 sums (<= a few ulps,
-        # inside the 1e-5 parity envelope) — skip the replay window
-        # plan entirely and emit one [0, flush] range
-        d0 = np.zeros((f,), np.float64)
-        if delays is not None:
-            dv = np.asarray(delays, np.float64).reshape(-1)
-            d0[:len(dv)] = dv
-        delays_win = d0[None, :]
-        history = []
-        if t_end is None:
-            last_real = rows.times[np.arange(f), rows.n_samples - 1] \
-                .astype(np.float64)
-            t_end = float((last_real[:n] - d0[:n]).max())
-    hi = int(np.floor((float(t_end) - origin) / step + 1e-9))
-    if hi >= next_slot:                   # the flush window
-        emits.append((next_slot, hi, len(delays_win) - 1))
-        next_slot = hi + 1
-    n_slots = next_slot
-
-    # re-chunk emit windows into fixed-size scan steps (each step stays
-    # inside ONE emitted window, so it carries that window's delays)
-    blk = int(scan_block)
-    step_lo, step_cnt, step_w = [], [], []
-    for (lo, hi, w) in emits:
-        c = lo
-        while c <= hi:
-            cc = min(blk, hi - c + 1)
-            step_lo.append(c)
-            step_cnt.append(cc)
-            step_w.append(w)
-            c += cc
-    t_steps = len(step_lo)
-
-    # per-(step, row) search-slice plan: replicate the scan body's f32
-    # query arithmetic exactly, bracket each step's lower bounds with
-    # two vectorized searchsorteds per row, and size one static slice
-    # width that covers the widest step
-    s_pad = rows_t.shape[1]
-    width = min(64, s_pad)
-    starts = np.zeros((max(t_steps, 1), f), np.int32)
-    if t_steps:
-        lo_arr = np.asarray(step_lo, np.int64)
-        hi_arr = lo_arr + np.asarray(step_cnt, np.int64) - 1
-        d32 = delays_win[np.asarray(step_w)].astype(np.float32)
-        q_lo = (origin + step * lo_arr).astype(np.float32)[:, None] + d32
-        q_hi = (origin + step * hi_arr).astype(np.float32)[:, None] + d32
-        ends = np.zeros((t_steps, f), np.int64)
-        for r in range(f):
-            starts[:, r] = np.searchsorted(rows_t[r], q_lo[:, r],
-                                           side="left")
-            ends[:, r] = np.searchsorted(rows_t[r], q_hi[:, r],
-                                         side="left")
-        ends = np.minimum(ends, s_pad - 1)   # beyond-span queries mask
-        width = int((ends - starts).max()) + 1
-        width = min(max(_round_up(width, 64), 64), s_pad)
-        starts = np.clip(starts, 0, s_pad - width).astype(np.int32)
-
-    d = len(group_sizes)
-    ph = np.asarray(phases, np.float64).reshape(-1, 2)
-    p = len(ph)
-    qn = 1 << k_max
-    off = np.concatenate([[0], np.cumsum(group_sizes)]).astype(np.int64)
-    gidx = np.zeros((d, k_max), np.int32)
-    gmask = np.zeros((d, k_max), np.float64)
-    for di, kk in enumerate(group_sizes):
-        gidx[di, :kk] = off[di] + np.arange(kk)
-        gmask[di, :kk] = 1.0
-
-    if t_steps:
-        xs = (np.asarray(step_lo, np.int64),
-              np.asarray(step_cnt, np.int32), starts,
-              np.ascontiguousarray(
-                  delays_win[np.asarray(step_w)].astype(np.float32)))
-        carry0 = (np.zeros((n,)), np.zeros((n,)),
-                  np.full((d,), -np.inf), np.zeros((d,), bool),
-                  np.zeros((d, qn, p, k_max)))
-        with jax.enable_x64(True):
-            carry = _fused_scan_steps(
-                jax.tree.map(jnp.asarray, carry0),
-                jax.tree.map(jnp.asarray, xs),
-                jnp.asarray(rows_t), jnp.asarray(rows_v),
-                jnp.asarray(t_first.astype(rows_t.dtype)),
-                jnp.asarray(rows_t[:, -1]),
-                jnp.asarray(gidx), jnp.asarray(gmask),
-                jnp.asarray(ph), jnp.asarray(np.float64(origin)),
-                jnp.asarray(np.float64(step)), block=blk, width=width)
-        n_k, ssr, _, _, integrals = [np.asarray(c) for c in carry]
-    else:
-        n_k = np.zeros((n,))
-        ssr = np.zeros((n,))
-        integrals = np.zeros((d, qn, p, k_max))
-
-    w_flat = _ivw_weights(n_k, ssr, var_floor)
-    out = np.zeros((d, p))
-    lo = 0
-    for di, kk in enumerate(group_sizes):
-        wv = w_flat[lo:lo + kk]
-        for pat in range(1, 1 << kk):
-            member = (pat >> np.arange(kk)) & 1
-            w_tot = float((wv * member).sum())
-            if w_tot > 0:
-                out[di] += integrals[di, pat][:, :kk] @ wv / w_tot
-        lo += kk
-    return ScanResult(totals=out, weights=w_flat,
-                      delays=np.asarray(delays_win[-1][:n],
-                                        np.float64).copy(),
-                      history=history, n_steps=t_steps, n_slots=n_slots)
-
-
 def attribute_energy_fused_streaming(trace_groups, phases, *,
                                      config=None, reference=None,
                                      corrections=None, registry=None,
@@ -3111,7 +2622,7 @@ def attribute_energy_fused_streaming(trace_groups, phases, *,
     one ``[PhaseEnergy]`` per group.
 
     config: a ``fleet.config.PipelineConfig`` (or one of its sections,
-    auto-wrapped) holding the chunk/grid/dtype/engine knobs
+    auto-wrapped) holding the chunk/grid/dtype knobs
     (``StreamConfig``), the delay-tracking geometry (``TrackConfig``),
     checkpointing (``CheckpointConfig``), plus ``health`` and ``dq``.
     ``StreamConfig.grid`` (absolute) pins the output grid for
@@ -3121,43 +2632,35 @@ def attribute_energy_fused_streaming(trace_groups, phases, *,
     identically — through ``fleet.config.resolve_config`` but emit a
     ``DeprecationWarning``.
 
-    engine: ``"windowed"`` drives the per-window stage chain (the
-    oracle, and the only multi-host path); ``"scan"`` plans the same
-    replay on the host and executes it as one jitted ``lax.scan``
-    (``attribute_totals_fused_scan``) — same results to <= 1e-5,
-    several times the throughput (see ``benchmarks/bench_stream.py``).
-
     health: None/False disables diagnostics (the default — results are
     then byte-for-byte today's pipeline); True or a
     ``health.HealthConfig`` composes a ``SensorHealthStage`` between
-    Fuse and PhaseAttribute (windowed engine only).  registry: an
-    optional ``health.HealthRegistry`` for telemetry export.
+    Fuse and PhaseAttribute.  registry: an optional
+    ``health.HealthRegistry`` for telemetry export.
     meter: a list of ``SlotSegment`` (absolute seconds, like phases)
-    composes a ``MeteringStage`` before PhaseAttribute (windowed engine
-    only) — per-request energies via ``pipe.request_energies()`` with
-    ``return_pipe=True``.
-    return_pipe: also return the driven pipeline (windowed engine), for
-    health-event/metrics/metering inspection: ``(out, pipe)``.
+    composes a ``MeteringStage`` before PhaseAttribute — per-request
+    energies via ``pipe.request_energies()`` with ``return_pipe=True``.
+    return_pipe: also return the driven pipeline, for health-event/
+    metrics/metering inspection: ``(out, pipe)``.
 
-    Fault tolerance (windowed engine only): ``CheckpointConfig(dir=,
-    every=K)`` writes an elastic carry checkpoint every K replay
-    windows; ``resume=True`` reloads the newest complete one and
-    SKIPS the already-processed windows — the resumed run's fused
-    energies are bit-identical to the uninterrupted run (the carries
-    are exact).  ``on_window(pipe, w)`` fires after window ``w``
-    (1-based) completes — test hook for kill injection.
+    Fault tolerance: ``CheckpointConfig(dir=, every=K)`` writes an
+    elastic carry checkpoint every K replay windows; ``resume=True``
+    reloads the newest complete one and SKIPS the already-processed
+    windows — the resumed run's fused energies are bit-identical to
+    the uninterrupted run (the carries are exact).
+    ``on_window(pipe, w)`` fires after window ``w`` (1-based)
+    completes — test hook for kill injection.
     ``PipelineConfig.dq``: a ``DataQualityPolicy`` for the
     ingest/fuse stages.
     """
     from repro.core.attribution import PhaseEnergy
     cfg = resolve_config(config, legacy,
                          "attribute_energy_fused_streaming")
-    chunk, engine = cfg.stream.chunk, cfg.stream.engine
+    chunk = cfg.stream.chunk
     grid, grid_step = cfg.stream.grid, cfg.stream.grid_step
     dtype, var_floor = cfg.stream.dtype, cfg.stream.var_floor
     use_t_measured = cfg.stream.use_t_measured
     interpret, use_kernel = cfg.stream.interpret, cfg.stream.use_kernel
-    host = cfg.stream.host
     track, delays = cfg.track.track, cfg.track.delays
     window, hop = cfg.track.window, cfg.track.hop
     max_lag, ema = cfg.track.max_lag, cfg.track.ema
@@ -3186,9 +2689,7 @@ def attribute_energy_fused_streaming(trace_groups, phases, *,
                 origin = float(rows.times[:rows.n_streams, 0]
                                .astype(np.float64).min())
                 t_end = None
-            if tail is None and engine == "windowed":
-                # the scan engine has no carry tail — don't pay the
-                # cadence scan
+            if tail is None:
                 tail = default_tail(rows, chunk, delays=delays,
                                     max_lag=max_lag, grid_step=grid_step)
             ref = None
@@ -3203,66 +2704,42 @@ def attribute_energy_fused_streaming(trace_groups, phases, *,
             if not phases:
                 return [[] for _ in groups]
             windows = [(a - rows.t0, b - rows.t0) for _, a, b in phases]
-            assert engine in ("windowed", "scan"), engine
-            if health:
-                assert engine == "windowed", \
-                    "the health stage composes with the windowed engine only"
             if meter:
-                assert engine == "windowed", \
-                    "the metering stage composes with the windowed " \
-                    "engine only"
                 meter = [s.shifted(-rows.t0) for s in meter]
-            if checkpoint_dir is not None or resume or on_window is not None:
-                assert engine == "windowed", \
-                    "checkpointing drives the windowed engine only"
-            if engine == "windowed":
-                pipe = StreamingFusedPipeline(
-                    [len(g) for g in groups], windows, grid_origin=origin,
-                    grid_step=grid_step, kind_row=rows.kind_row,
-                    delays=delays, reference=ref, track=track,
-                    window=window, hop=hop, max_lag=max_lag, ema=ema,
-                    tail=tail, var_floor=var_floor, dtype=dtype,
-                    interpret=interpret, use_kernel=use_kernel, host=host,
-                    health=health, registry=registry,
-                    health_names=[tr.name for tr in flat], meter=meter,
-                    dq_policy=dq_policy)
-                n_win, idx = _replay_window_plan(rows, chunk)
-        if engine == "scan":
-            assert not return_pipe, "return_pipe needs the windowed engine"
-            with tracing.span("fleet.scan", n=rows.shape[1]):
-                res = attribute_totals_fused_scan(
-                    rows, [len(g) for g in groups], windows,
-                    grid_origin=origin, grid_step=grid_step, t_end=t_end,
-                    chunk=chunk, delays=delays, reference=ref, track=track,
-                    window=window, hop=hop, max_lag=max_lag, ema=ema,
-                    var_floor=var_floor, interpret=interpret,
-                    use_kernel=use_kernel, host=host)
-            totals = res.totals
-            pipe = None
-        else:
-            start_w = 0
-            if resume:
-                assert checkpoint_dir is not None, \
-                    "resume=True needs checkpoint_dir"
-                try:
-                    start_w = pipe.restore(checkpoint_dir)
-                except FileNotFoundError:
-                    start_w = 0      # cold start: nothing published yet
-            # windows up to start_w were folded before the checkpoint
-            for w in range(start_w + 1, n_win + 1):
-                with tracing.span("fleet.window",
-                                  n=_window_width(idx, w - 1)):
-                    t_blk, v_blk = _replay_window(rows, idx, w - 1)
-                    pipe.update(t_blk, v_blk)
-                    if (checkpoint_dir is not None and checkpoint_every
-                            and w % checkpoint_every == 0):
-                        pipe.checkpoint(checkpoint_dir)
-                    if on_window is not None:
-                        on_window(pipe, w)
-            with tracing.span("fleet.finalize"):
-                pipe.finalize(t_end)
-            with tracing.span("fleet.totals"):
-                totals = pipe.totals()
+            pipe = StreamingFusedPipeline(
+                [len(g) for g in groups], windows, grid_origin=origin,
+                grid_step=grid_step, kind_row=rows.kind_row,
+                delays=delays, reference=ref, track=track,
+                window=window, hop=hop, max_lag=max_lag, ema=ema,
+                tail=tail, var_floor=var_floor, dtype=dtype,
+                interpret=interpret, use_kernel=use_kernel,
+                health=health, registry=registry,
+                health_names=[tr.name for tr in flat], meter=meter,
+                dq_policy=dq_policy)
+            n_win, idx = _replay_window_plan(rows, chunk)
+        start_w = 0
+        if resume:
+            assert checkpoint_dir is not None, \
+                "resume=True needs checkpoint_dir"
+            try:
+                start_w = pipe.restore(checkpoint_dir)
+            except FileNotFoundError:
+                start_w = 0      # cold start: nothing published yet
+        # windows up to start_w were folded before the checkpoint
+        for w in range(start_w + 1, n_win + 1):
+            with tracing.span("fleet.window",
+                              n=_window_width(idx, w - 1)):
+                t_blk, v_blk = _replay_window(rows, idx, w - 1)
+                pipe.update(t_blk, v_blk)
+                if (checkpoint_dir is not None and checkpoint_every
+                        and w % checkpoint_every == 0):
+                    pipe.checkpoint(checkpoint_dir)
+                if on_window is not None:
+                    on_window(pipe, w)
+        with tracing.span("fleet.finalize"):
+            pipe.finalize(t_end)
+        with tracing.span("fleet.totals"):
+            totals = pipe.totals()
         with tracing.span("fleet.rows"):
             out = []
             for di in range(len(groups)):

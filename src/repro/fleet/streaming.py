@@ -26,9 +26,7 @@ Reordered timestamps (rare tool-jitter artifact) would lose their dE to
 the clamped overlap, so the Ingest stage sanitizes chunks on the host
 (see ``pipeline.sanitize_chunk``).  For the full streaming-fused chain
 (online delay tracking + regrid + inverse-variance fusion) see
-``pipeline.StreamingFusedPipeline`` — or its single-``lax.scan`` replay
-engine ``pipeline.attribute_totals_fused_scan`` when the whole run is
-available for replay.
+``pipeline.StreamingFusedPipeline``.
 """
 from __future__ import annotations
 

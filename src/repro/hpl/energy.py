@@ -74,7 +74,7 @@ def fused_fleet_energize(tracer: RegionTracer, n_nodes, *, n_chips=4,
                          seed0=0, sensors_per_chip=3, config=None,
                          interpret=_UNSET, streaming=False,
                          track=_UNSET, chunk=_UNSET, shard=None,
-                         collectives=None, engine=_UNSET):
+                         collectives=None):
     """Per-node phase energies from FUSED cross-sensor streams.
 
     Where ``fleet_energize`` trusts chip0's energy counter alone, this
@@ -88,14 +88,11 @@ def fused_fleet_energize(tracer: RegionTracer, n_nodes, *, n_chips=4,
     ``streaming=True`` runs the same accounting through the streaming
     stage pipeline (``fleet.pipeline``); ``config`` (a
     ``fleet.config.PipelineConfig`` or section) carries its knobs —
-    the flat ``chunk``/``track``/``engine``/``interpret`` kwargs still
+    the flat ``chunk``/``track``/``interpret`` kwargs still
     resolve bit-identically on that path but are deprecated.  The
     replay runs in chunk-sized windows:
     O(fleet x chunk) memory and online per-sensor delay tracking — the
-    long-HPL-run mode where sensor clocks drift.  ``engine="scan"``
-    executes that replay as one jitted ``lax.scan``
-    (``fleet.pipeline.attribute_totals_fused_scan``): same energies to
-    <= 1e-5, several times the throughput.
+    long-HPL-run mode where sensor clocks drift.
 
     ``shard``+``collectives`` split the fleet across ``jax.distributed``
     processes: this host simulates (in production: reads) ONLY the
@@ -112,7 +109,6 @@ def fused_fleet_energize(tracer: RegionTracer, n_nodes, *, n_chips=4,
     from repro.core.calibration import nic_rail_corrections
     from repro.fleet.config import resolve_config
     legacy = {k: v for k, v in dict(track=track, chunk=chunk,
-                                    engine=engine,
                                     interpret=interpret).items()
               if v is not _UNSET}
     shifted, truth = phases_and_truth(tracer)

@@ -130,7 +130,7 @@ class _AttributionMixin:
                          t_shift=0.0, use_fleet=True, config=None,
                          chunk=_UNSET, fuse=False, reference=None,
                          streaming=False, track=_UNSET, delays=_UNSET,
-                         shard=None, collectives=None, engine=_UNSET,
+                         shard=None, collectives=None,
                          health=_UNSET, registry=None):
         """Per-phase energy for the engine's recorded serving phases.
 
@@ -154,7 +154,7 @@ class _AttributionMixin:
         O(fleet x chunk) memory — instead of the batch align-and-fuse.
         ``config`` (a ``fleet.config.PipelineConfig`` or section)
         carries the streaming pipeline's knobs; the flat
-        ``chunk``/``track``/``delays``/``engine``/``health`` kwargs
+        ``chunk``/``track``/``delays``/``health`` kwargs
         still resolve bit-identically but are deprecated on the
         streaming paths.  ``track``/``delays`` pin the tracking mode:
         fixed per-sensor ``delays`` (track=False) or online tracking
@@ -166,13 +166,9 @@ class _AttributionMixin:
         energies; online tracking state is synchronized over the
         collectives, so tracked multi-host runs apply the same delay
         corrections as the single-host tracker (see
-        ``repro.distributed.multihost``).  ``engine="scan"``
-        (single-host streaming only) executes the replay as one jitted
-        ``lax.scan`` (``fleet.pipeline.attribute_totals_fused_scan``) —
-        same energies to <= 1e-5, several times the throughput.
-        ``health`` (streaming only) composes the
-        ``repro.health.SensorHealthStage`` fleet-health diagnostics
-        into the pipeline (``True`` or a ``HealthConfig``);
+        ``repro.distributed.multihost``).  ``health`` (streaming only)
+        composes the ``repro.health.SensorHealthStage`` fleet-health
+        diagnostics into the pipeline (``True`` or a ``HealthConfig``);
         ``registry`` (a ``HealthRegistry``, defaulting to the engine's
         own) collects the health + pipeline self-metrics for export.
         """
@@ -180,7 +176,7 @@ class _AttributionMixin:
         phases = [(n, a + t_shift, b + t_shift)
                   for n, a, b in self.tracer.phases(depth=depth)]
         legacy = _explicit(chunk=chunk, track=track, delays=delays,
-                           engine=engine, health=health)
+                           health=health)
         if fuse:
             assert isinstance(traces, dict), \
                 "fuse=True groups by sensor name and needs dict input"
@@ -493,8 +489,8 @@ class ServeEngine(_AttributionMixin):
                            registry=None) -> RequestEnergyReport:
         """Split fused phase energy across requests -> energy bills.
 
-        Runs the streaming fused pipeline (windowed engine) with the
-        slot-segment schedule composed as a ``MeteringStage``: each
+        Runs the streaming fused pipeline with the slot-segment
+        schedule composed as a ``MeteringStage``: each
         segment's energy is divided across its concurrently-active
         requests by token-weighted occupancy.  Returns a
         :class:`RequestEnergyReport` (J/request, J/token, percentiles,

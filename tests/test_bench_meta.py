@@ -6,7 +6,7 @@ execute — and pin the three CI contracts:
   * the baseline-registry sync gate: every bench registered in
     ``run.py``'s BENCHES needs a baseline entry, so a new benchmark
     cannot land ungated;
-  * per-bench ``floors``: derived metrics (fused-scan throughput,
+  * per-bench ``floors``: derived metrics (throughput ratios,
     wire-compression ratio, ...) are hard minimums, and a baseline
     refresh (``--write-baseline``) preserves them verbatim;
   * parity capture: every ``*rel_err`` derived key is recorded as a
@@ -52,12 +52,11 @@ def test_registry_gate_fails_on_missing_entry(compare_mod):
 
 
 def test_gated_metrics_present_in_baselines(compare_mod):
-    """The tentpole's metrics are actually wired into the gate: both
-    baselines floor the fused-scan throughput and the wire compression."""
+    """The gated metrics are actually wired into the gate: both
+    baselines floor the wire compression."""
     for fname in ("baseline.json", "baseline-full.json"):
         base = json.loads((BENCH_DIR / fname).read_text())
         floors = base["bench_stream"].get("floors", {})
-        assert "scan_thr" in floors, fname
         assert "wire_ratio" in floors, fname
         assert "wire_ratio" in base["bench_multihost"].get("floors", {}), \
             fname
@@ -90,11 +89,11 @@ def test_ft_resume_exact_gated_in_baselines(compare_mod):
 
 def test_floor_gate(compare_mod, tmp_path):
     baseline = {"bench_a": {"us_per_call": 100.0, "parity": {},
-                            "floors": {"scan_thr": 1.5,
+                            "floors": {"thr_ratio": 1.5,
                                        "wire_ratio": 10.0}}}
     csv = tmp_path / "r.csv"
     csv.write_text("name,us_per_call,derived\n"
-                   "bench_a,120,scan_thr=x1.80,wire_ratio=x9.1\n")
+                   "bench_a,120,thr_ratio=x1.80,wire_ratio=x9.1\n")
     results = compare_mod.parse_results(csv)
     _, fails = compare_mod.compare(baseline, results, max_slowdown=1.5,
                                    min_us=500.0, parity_floor=1e-9)
@@ -104,13 +103,13 @@ def test_floor_gate(compare_mod, tmp_path):
 
 def test_floor_gate_fails_on_missing_metric(compare_mod, tmp_path):
     baseline = {"bench_a": {"us_per_call": 100.0, "parity": {},
-                            "floors": {"scan_thr": 1.5}}}
+                            "floors": {"thr_ratio": 1.5}}}
     csv = tmp_path / "r.csv"
     csv.write_text("name,us_per_call,derived\nbench_a,120,eff=x1.0\n")
     results = compare_mod.parse_results(csv)
     _, fails = compare_mod.compare(baseline, results, max_slowdown=1.5,
                                    min_us=500.0, parity_floor=1e-9)
-    assert any("floor metric scan_thr missing" in f for f in fails)
+    assert any("floor metric thr_ratio missing" in f for f in fails)
 
 
 def test_parse_results_strips_ratio_prefix(compare_mod, tmp_path):
@@ -128,14 +127,14 @@ def test_write_baseline_preserves_floors_and_rel_err(compare_mod,
                        "floors": {"wire_ratio": 10.0}}}
     csv = tmp_path / "r.csv"
     csv.write_text("name,us_per_call,derived\n"
-                   "bench_a,80,wire_ratio=x12.6,scan_rel_err=9.2e-07,"
+                   "bench_a,80,wire_ratio=x12.6,stream_rel_err=9.2e-07,"
                    "rel_err=1.0e-07\n")
     results = compare_mod.parse_results(csv)
     out = tmp_path / "base.json"
     compare_mod.write_baseline(results, out, old=old)
     base = json.loads(out.read_text())
     assert base["bench_a"]["floors"] == {"wire_ratio": 10.0}
-    assert base["bench_a"]["parity"] == {"scan_rel_err": 9.2e-07,
+    assert base["bench_a"]["parity"] == {"stream_rel_err": 9.2e-07,
                                          "rel_err": 1.0e-07}
     assert base["bench_a"]["us_per_call"] == 80.0
 

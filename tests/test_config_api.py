@@ -14,9 +14,13 @@ from repro.fleet import (CheckpointConfig, PipelineConfig, StreamConfig,
                          resolve_config)
 
 
+def _truth(span_s=3.0):
+    return square_wave(span_s / 4.0, 3, lead_s=span_s / 8,
+                       tail_s=span_s / 8)
+
+
 def _sim_groups(n_devices=2, seed=0, span_s=3.0):
-    truth = square_wave(span_s / 4.0, 3, lead_s=span_s / 8,
-                        tail_s=span_s / 8)
+    truth = _truth(span_s)
     tool = ToolSpec(0.9e-3)
     groups = []
     for d in range(n_devices):
@@ -81,6 +85,16 @@ def test_unknown_legacy_kwarg_is_a_typeerror():
         resolve_config(None, {"bogus": 1}, "f")
 
 
+@pytest.mark.parametrize("name", ["engine", "host"])
+def test_retired_stream_kwargs_are_typeerrors(name):
+    """``engine`` and ``host`` are no longer StreamConfig fields: the
+    flat kwargs fail like any unknown keyword."""
+    with pytest.raises(TypeError, match=name):
+        resolve_config(None, {name: True}, "f")
+    with pytest.raises(TypeError):
+        StreamConfig(**{name: True})
+
+
 def test_mixing_config_and_legacy_is_a_typeerror():
     with pytest.raises(TypeError, match="both config="):
         resolve_config(PipelineConfig(), {"chunk": 8}, "f")
@@ -108,23 +122,31 @@ def test_batch_api_rejects_config(setup):
                                config=PipelineConfig())
 
 
-@pytest.mark.parametrize("engine", ["windowed", "scan"])
-def test_legacy_and_config_calls_bit_identical(setup, engine):
+@pytest.mark.parametrize("tracked", [
+    pytest.param(False, id="pinned-delays-untracked"),
+    pytest.param(True, id="tracked-reference"),
+])
+def test_legacy_and_config_calls_bit_identical(setup, tracked):
     """The acceptance bar: a legacy-kwarg call and the equivalent
     ``config=`` call produce bit-identical energies (both resolve to
     the same PipelineConfig), and only the legacy one warns."""
     groups, grid, d_all, phases = setup
+    if tracked:
+        flat = dict(track=True, window=512, hop=128)
+        ref = _truth()
+    else:
+        flat = dict(delays=d_all)
+        ref = None
     with pytest.warns(DeprecationWarning, match="chunk"):
         legacy = attribute_energy_fused_streaming(
-            groups, phases, grid=grid, delays=d_all, chunk=257,
-            engine=engine)
+            groups, phases, grid=grid, chunk=257, reference=ref, **flat)
     cfg = PipelineConfig(
-        stream=StreamConfig(grid=grid, chunk=257, engine=engine),
-        track=TrackConfig(delays=d_all))
+        stream=StreamConfig(grid=grid, chunk=257),
+        track=TrackConfig(**flat))
     with warnings.catch_warnings():
         warnings.simplefilter("error", DeprecationWarning)
-        modern = attribute_energy_fused_streaming(groups, phases,
-                                                  config=cfg)
+        modern = attribute_energy_fused_streaming(
+            groups, phases, config=cfg, reference=ref)
     for rl, rm in zip(legacy, modern):
         for pl, pm in zip(rl, rm):
             assert pl.phase == pm.phase

@@ -41,15 +41,42 @@ def _sim_groups(n_devices, seed=0, span_s=4.5, noise=3.0):
     return truth, groups
 
 
+def _sim_unequal_groups(sizes, span_s=2.5):
+    """Device groups of unequal size (alternating wrapping energy
+    counters and noisy power sensors) — the row padding of the smaller
+    groups must not leak into the fusion statistics."""
+    truth = square_wave(span_s / 4.0, 3, lead_s=span_s / 8,
+                        tail_s=span_s / 8)
+    tool = ToolSpec(0.9e-3)
+    groups, i = [], 0
+    for d, sz in enumerate(sizes):
+        grp = []
+        for j in range(sz):
+            kind = "energy_cum" if j % 2 == 0 else "power_inst"
+            sp = SensorSpec(name=f"d{d}_{j}", scope="chip", kind=kind,
+                            quantum=1e-6,
+                            wrap_bits=26 if kind == "energy_cum" else 0,
+                            noise_w=0.0 if kind == "energy_cum" else 3.0,
+                            delay_s=0.002 * (i % 7))
+            grp.append(simulate_sensor(sp, tool, truth, seed=100 + 17 * i))
+            i += 1
+        groups.append(grp)
+    return truth, groups
+
+
 def _parity_phases(grid, n=6):
     edges = np.linspace(float(grid[0]), float(grid[-1]), n + 1)
     return [(f"p{k}", float(a), float(b))
             for k, (a, b) in enumerate(zip(edges[:-1], edges[1:]))]
 
 
-def _run_parity(n_devices, chunk, span_s=4.5, min_chunks=None):
+def _run_parity(n_devices, chunk, span_s=4.5, min_chunks=None,
+                sizes=None):
     from repro.align import align_and_fuse, attribute_energy_fused
-    truth, groups = _sim_groups(n_devices, span_s=span_s)
+    if sizes is None:
+        truth, groups = _sim_groups(n_devices, span_s=span_s)
+    else:
+        truth, groups = _sim_unequal_groups(sizes)
     fused = align_and_fuse(groups, reference=truth)
     grid = fused[0].grid
     d_all = np.concatenate([fs.delays for fs in fused])
@@ -71,10 +98,17 @@ def _run_parity(n_devices, chunk, span_s=4.5, min_chunks=None):
     return worst
 
 
-def test_streaming_fused_matches_batch_small():
+@pytest.mark.parametrize("chunk,sizes", [
+    pytest.param(193, None, id="193"),
+    pytest.param(257, None, id="257"),
+    pytest.param(512, None, id="512"),
+    pytest.param(200, [1, 3, 2], id="groups-1-3-2"),
+])
+def test_streaming_fused_matches_batch_small(chunk, sizes):
     """Chunked streaming pipeline == batch align_and_fuse ->
-    attribute_energy_fused at <=1e-5 (fixed delays, same grid)."""
-    worst = _run_parity(2, chunk=257)
+    attribute_energy_fused at <=1e-5 (fixed delays, same grid), for
+    several chunk widths and for unequal group sizes."""
+    worst = _run_parity(2, chunk=chunk, sizes=sizes)
     assert worst <= 1e-5, worst
 
 
@@ -84,20 +118,51 @@ def test_streaming_fused_long_run_parity():
     assert worst <= 1e-5, worst
 
 
-def test_streaming_fused_online_tracking_close_to_batch():
+@pytest.mark.parametrize("chunk,window,hop,with_ref", [
+    pytest.param(512, 1024, 256, True, id="reference"),
+    pytest.param(256, 512, 128, True, id="reference-short-window"),
+    pytest.param(256, 512, 128, False, id="self-reference"),
+])
+def test_streaming_fused_online_tracking_close_to_batch(chunk, window,
+                                                        hop, with_ref):
     """With delays estimated ONLINE (sliding windows) instead of fixed,
-    the streamed energies stay within ~2% of the batch path."""
+    the streamed energies stay within ~2% of the batch path — against
+    a known reference, or (no reference, derived default grid) against
+    each group's own first stream."""
     from repro.align import attribute_energy_fused
     truth, groups = _sim_groups(2)
     phases = [("a", 0.8, 1.8), ("b", 2.0, 3.6)]
-    batch = attribute_energy_fused(groups, phases, reference=truth)
+    ref = truth if with_ref else None
+    batch = attribute_energy_fused(groups, phases, reference=ref)
     stream = attribute_energy_fused_streaming(
-        groups, phases, reference=truth, chunk=512, window=1024,
-        hop=256, max_lag=64)
+        groups, phases, reference=ref, chunk=chunk, window=window,
+        hop=hop, max_lag=64)
     for rb, rs in zip(batch, stream):
         for pb, ps in zip(rb, rs):
             assert abs(ps.energy_j - pb.energy_j) \
                 <= 0.02 * max(abs(pb.energy_j), 1.0), pb.phase
+
+
+def test_streaming_fused_tracks_configured_delays():
+    """``return_pipe=True`` hands back the driven pipeline: tracking
+    against the truth recovers each energy counter's configured delay
+    within a few grid steps, and every end-of-run IVW weight is
+    positive."""
+    from repro.fleet import PipelineConfig, StreamConfig, TrackConfig
+    truth, groups = _sim_groups(2, seed=9, span_s=3.0)
+    phases = [("a", 0.6, 1.4), ("b", 1.6, 2.6)]
+    cfg = PipelineConfig(stream=StreamConfig(chunk=256),
+                         track=TrackConfig(track=True, window=512,
+                                           hop=128))
+    out, pipe = attribute_energy_fused_streaming(
+        groups, phases, config=cfg, reference=truth, return_pipe=True)
+    assert len(out) == 2 and all(len(r) == 2 for r in out)
+    assert len(pipe.delay_history) > 0          # the tracker fired
+    got = pipe.delays()[::2]                     # the energy rows
+    want = np.asarray([0.004 * (d % 5) for d in range(2)])
+    assert np.all(np.abs(got - want) <= 2e-3), (got, want)
+    for w in pipe.weights():
+        assert w.shape == (2,) and (w > 0).all(), w
 
 
 def test_streaming_fused_row_count_off_tile():

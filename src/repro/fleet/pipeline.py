@@ -1220,7 +1220,16 @@ class FusedPhaseAttributeStage:
             assert shard is not None, \
                 "multi-host totals need the HostShard (global row ids)"
             assert list(shard.local_group_sizes) == self.group_sizes
-        self.carry = self._fresh()
+        sizes = np.asarray(self.group_sizes, np.int64)
+        self._row_lo = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+        # size classes: (k, device ids, (G_k, k) row ids) — one gather
+        # per window takes every k-row group's rows at once
+        self._classes = []
+        for k in np.unique(sizes):
+            dev = np.nonzero(sizes == k)[0]
+            self._classes.append(
+                (int(k), dev, self._row_lo[dev][:, None] + np.arange(k)))
+        self.reset()
 
     def _fresh(self):
         d = len(self.group_sizes)
@@ -1229,37 +1238,90 @@ class FusedPhaseAttributeStage:
 
     def reset(self):
         self.carry = self._fresh()
+        self.groups_batched = 0    # group-windows folded in a size class
+        self.groups_looped = 0     # group-windows folded one at a time
         return self
 
     def update(self, gw: GriddedWindow):
-        a = self.phases[:, 0][:, None]
-        b = self.phases[:, 1][:, None]
-        lo = 0
-        for d, k in enumerate(self.group_sizes):
-            hi = lo + k
-            m = gw.mask[lo:hi]
-            anyv = m.any(axis=0)
-            if anyv.any():
-                sel = np.nonzero(anyv)[0]
-                tv = gw.grid[sel]
-                tp = self.carry.t_prev[d]
-                if not np.isfinite(tp):
-                    tp = tv[0]               # zero-width seed
-                t_lo = np.concatenate([[tp], tv[:-1]])
-                ov = np.clip(np.minimum(tv[None, :], b)
-                             - np.maximum(t_lo[None, :], a), 0.0, None)
-                mm = m[:, sel]
-                vv = gw.values[lo:hi][:, sel].astype(np.float64) * mm
-                bits = (1 << np.arange(k, dtype=np.int64))[:, None]
-                pat = (mm * bits).sum(axis=0)
-                for p in np.unique(pat):
-                    ps = pat == p
-                    acc = self.carry.integrals[d].setdefault(
-                        int(p), np.zeros((self.n_phases, k)))
-                    acc += ov[:, ps] @ vv[:, ps].T
-                self.carry.t_prev[d] = tv[-1]
-            lo = hi
+        """Fold one window.  A group whose every row covers every slot,
+        past its seed window, joins its size class's batch: they share
+        one valid-slot set, so one stacked product folds them all.
+        Every other group folds alone (partial coverage, its seed
+        window).  Either way a group's sums depend only on its own rows
+        and the window's shape, never on which groups share the batch,
+        so the fleet result stays bit-identical under any host<-group
+        assignment."""
+        row_full = gw.mask.all(axis=1)
+        seeded = np.isfinite(self.carry.t_prev)
+        batched = np.zeros(seeded.shape, bool)
+        batches = []
+        for k, dev, rows in self._classes:
+            take = row_full[rows].all(axis=1) & seeded[dev]
+            batches.append((k, dev[take], rows[take]))
+            batched[dev[take]] = True
+        looped = np.flatnonzero(~batched)
+        n_batched = len(batched) - len(looped)
+        with tracing.span("fleet.fold", n=n_batched, looped=len(looped)):
+            for k, dev, rows in batches:
+                self._fold_class(gw, k, dev, rows)
+        for d in looped:
+            self._fold_group(gw, int(d))
+        self.groups_batched += n_batched
+        self.groups_looped += len(looped)
         return None
+
+    def _fold_class(self, gw: GriddedWindow, k: int, dev, rows):
+        """Fully covered k-row groups: slots 1.. share one (P, S-1)
+        overlap matrix; slot 0 opens at each group's own ``t_prev``, a
+        rank-1 term of its own.  ``np.matmul`` takes one (P, S-1) @
+        (S-1, k) product per group; nothing sums across groups."""
+        a = self.phases[:, :1]
+        b = self.phases[:, 1:]
+        grid = gw.grid
+        vv = gw.values[rows].astype(np.float64)          # (G, k, S)
+        ov = np.clip(np.minimum(grid[1:], b) - np.maximum(grid[:-1], a),
+                     0.0, None)
+        blocks = np.matmul(ov, vv[:, :, 1:].transpose(0, 2, 1))
+        tp = self.carry.t_prev[dev][:, None, None]       # (G, 1, 1)
+        ov0 = np.clip(np.minimum(grid[0], b) - np.maximum(tp, a), 0.0,
+                      None)                              # (G, P, 1)
+        blocks += ov0 * vv[:, None, :, 0]
+        full = (1 << k) - 1
+        for d, blk in zip(dev, blocks):
+            acc = self.carry.integrals[d].setdefault(
+                full, np.zeros((self.n_phases, k)))
+            acc += blk
+        self.carry.t_prev[dev] = grid[-1]
+
+    def _fold_group(self, gw: GriddedWindow, d: int):
+        """One group, any coverage: bridge invalid slots, one product
+        per coverage pattern."""
+        a = self.phases[:, :1]
+        b = self.phases[:, 1:]
+        k = self.group_sizes[d]
+        lo = self._row_lo[d]
+        m = gw.mask[lo:lo + k]
+        anyv = m.any(axis=0)
+        if not anyv.any():
+            return
+        sel = np.nonzero(anyv)[0]
+        tv = gw.grid[sel]
+        tp = self.carry.t_prev[d]
+        if not np.isfinite(tp):
+            tp = tv[0]               # zero-width seed
+        t_lo = np.concatenate([[tp], tv[:-1]])
+        ov = np.clip(np.minimum(tv[None, :], b)
+                     - np.maximum(t_lo[None, :], a), 0.0, None)
+        mm = m[:, sel]
+        vv = gw.values[lo:lo + k][:, sel].astype(np.float64) * mm
+        bits = (1 << np.arange(k, dtype=np.int64))[:, None]
+        pat = (mm * bits).sum(axis=0)
+        for p in np.unique(pat):
+            ps = pat == p
+            acc = self.carry.integrals[d].setdefault(
+                int(p), np.zeros((self.n_phases, k)))
+            acc += ov[:, ps] @ vv[:, ps].T
+        self.carry.t_prev[d] = tv[-1]
 
     def _gathered(self):
         """(integrals, group_sizes, w_flat): local, or the fleet-wide
